@@ -12,9 +12,10 @@ data plane's interface; two implementations:
   buckets padded to the GF kernel's TILE_L, batch padded to a power of
   two) and dispatches through the Pallas kernels in ``repro.kernels``:
   the bit-sliced GF(256) matmul for encode/decode and the lane-parallel
-  SHA-1 kernel for chunk ids.  On TPU the kernels run compiled; elsewhere
-  they run in interpret mode, so the engine stays byte-identical to
-  ``NumpyEngine`` everywhere (proven by the differential tests).
+  SHA-1 kernel for chunk ids.  On TPU it runs the compiled Pallas kernels;
+  elsewhere it runs their jitted pure-jnp oracles, so the engine stays
+  byte-identical to ``NumpyEngine`` everywhere (proven by the differential
+  tests).
 
 Both engines produce identical bytes, so every store-level artifact --
 piece placement, dedup ratio, ``StoreStats`` -- is engine-invariant.
@@ -239,10 +240,12 @@ class KernelEngine(CodingEngine):
     the XLA-compiled oracle on CPU.
 
     SHA-1 launches use a fixed batch of ``hash_batch`` messages padded to
-    ``max_hash_len`` bytes of message schedule, so every launch compiles
-    to one (hash_batch, M, 16) shape regardless of workload -- compile
-    once, reuse forever.  Chunks longer than ``max_hash_len`` would grow
-    that shape, so they take the host ``hash_fn`` fallback instead.
+    at most ``max_hash_len`` bytes of message schedule, so the compiled
+    (B, M, 16) shapes stay bounded regardless of workload.  Every chunk is
+    hashed on the device: a chunk longer than ``max_hash_len`` raises
+    ``ValueError``, so the store sizes the cap to its classes' largest
+    ``chunk_max``.  Only a custom ``hash_fn`` (which has no kernel twin)
+    hashes on the host.
     """
 
     name = "kernel"
@@ -256,6 +259,9 @@ class KernelEngine(CodingEngine):
         if impl is None:
             import jax
             impl = "kernel" if jax.default_backend() == "tpu" else "ref"
+            if impl == "kernel":
+                from repro.kernels import ops
+                ops.use_compile_cache()
         self.impl = impl
         self.max_hash_len = max_hash_len
         self.hash_batch = hash_batch or self.HASH_BATCH
@@ -303,19 +309,9 @@ class KernelEngine(CodingEngine):
             # custom id functions have no kernel twin -- host fallback
             return [self.hash_fn(c) for c in chunks]
         from repro.kernels import ops
-        out: list[bytes | None] = [None] * len(chunks)
-        batch: list[bytes] = []
-        batch_pos: list[int] = []
-        for i, c in enumerate(chunks):
-            if len(c) > self.max_hash_len:
-                # oversized chunk: padding it would grow the compiled
-                # (hash_batch, M, 16) launch shape -- hash on the host
-                out[i] = self.hash_fn(c)
-            else:
-                batch.append(c)
-                batch_pos.append(i)
-        for i in range(0, len(batch), self.hash_batch):
-            group = batch[i: i + self.hash_batch]
+        out: list[bytes] = []
+        for i in range(0, len(chunks), self.hash_batch):
+            group = chunks[i: i + self.hash_batch]
             # pad the batch axis to the next power of two (clamped to
             # hash_batch): a steady-state window of tens of chunks no
             # longer drags hash_batch-wide dead lanes through the
@@ -324,14 +320,14 @@ class KernelEngine(CodingEngine):
             target = min(1 << max(0, len(group) - 1).bit_length(),
                          self.hash_batch)
             pad = target - len(group)
+            # raises ValueError for a chunk over max_hash_len: growing the
+            # compiled block axis, or hashing it on the host, would hide it
             blocks, counts = hashing.sha1_pad_batch(
                 group + [b""] * pad, max_len=self.max_hash_len)
             words = ops.sha1_digest_words(blocks, counts, impl=self.impl)
-            digests = hashing.digest_words_to_bytes(np.asarray(words))
-            for pos, digest in zip(batch_pos[i: i + self.hash_batch],
-                                   digests):
-                out[pos] = digest
-        return out  # type: ignore[return-value]
+            words = np.asarray(words)[:len(group)]
+            out += hashing.digest_words_to_bytes(words)
+        return out
 
     def encode_blobs(self, code: RSCode,
                      blobs: list[bytes]) -> list[list[bytes]]:
@@ -396,7 +392,8 @@ class FusedEngine(KernelEngine):
         return ids, pieces
 
 
-def make_engine(spec, hash_fn=hashing.chunk_id) -> CodingEngine:
+def make_engine(spec, hash_fn=hashing.chunk_id,
+                max_hash_len: int = 8192) -> CodingEngine:
     """Resolve an engine spec to a ``CodingEngine``.
 
     Accepted specs: a ``CodingEngine`` instance, ``'numpy'`` (per-chunk
@@ -404,18 +401,25 @@ def make_engine(spec, hash_fn=hashing.chunk_id) -> CodingEngine:
     TPU, jitted ``'ref'`` oracles elsewhere), ``'fused'`` (kernel
     batching plus the fused single-residency hash+encode ingest), or the
     explicit overrides ``'ref'`` / ``'pallas'`` that pin the batched
-    implementation regardless of backend.
+    implementation regardless of backend.  ``max_hash_len`` is the
+    longest chunk the batched engines must hash on the device; a given
+    instance whose cap is shorter is refused.
     """
     if isinstance(spec, CodingEngine):
+        if getattr(spec, "max_hash_len", max_hash_len) < max_hash_len:
+            raise ValueError(
+                f"engine hashes chunks up to {spec.max_hash_len} bytes, "
+                f"the store's classes need {max_hash_len}")
         return spec
     if spec == "numpy":
         return NumpyEngine(hash_fn)
-    if spec == "kernel":
-        return KernelEngine(hash_fn)  # impl resolved from backend
-    if spec == "fused":
-        return FusedEngine(hash_fn)  # impl resolved from backend
+    if spec == "kernel":  # impl resolved from backend
+        return KernelEngine(hash_fn, max_hash_len=max_hash_len)
+    if spec == "fused":  # impl resolved from backend
+        return FusedEngine(hash_fn, max_hash_len=max_hash_len)
     if spec == "ref":
-        return KernelEngine(hash_fn, impl="ref")
+        return KernelEngine(hash_fn, impl="ref", max_hash_len=max_hash_len)
     if spec == "pallas":
-        return KernelEngine(hash_fn, impl="kernel")
+        return KernelEngine(hash_fn, impl="kernel",
+                            max_hash_len=max_hash_len)
     raise ValueError(f"unknown coding engine {spec!r}")

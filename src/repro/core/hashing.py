@@ -51,8 +51,8 @@ def sha1_pad_batch(chunks: list[bytes], max_len: int | None = None
 
     ``max_len`` (message bytes) is an *authoritative* cap on the block
     axis: a chunk that would not fit raises ``ValueError`` instead of
-    silently widening the compiled launch shape (callers route such
-    chunks to a host hash fallback).  Under the cap the block axis is
+    silently widening the compiled launch shape (callers size the cap to
+    the longest chunk they produce).  Under the cap the block axis is
     *bucketed* -- padded to the next power of two of the batch's own
     need, clamped to the cap -- so callers see a bounded set of compiled
     shapes ({1, 2, 4, ..., cap} blocks) instead of always paying the
@@ -69,7 +69,7 @@ def sha1_pad_batch(chunks: list[bytes], max_len: int | None = None
         if cap > fixed:
             raise ValueError(
                 f"chunk needs {cap} SHA-1 blocks > fixed cap {fixed} "
-                f"(max_len={max_len}); hash oversized chunks on the host")
+                f"(max_len={max_len}); raise the caller's max_len")
         cap = min(1 << (cap - 1).bit_length(), fixed)
     out = np.zeros((len(chunks), cap, 16), dtype=np.uint32)
     for i, p in enumerate(padded):

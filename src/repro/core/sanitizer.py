@@ -21,8 +21,10 @@ Three checks, mirroring the searslint static passes at runtime:
    attributed to this store never exceed it.  Budgets and attributed
    counts are cumulative over the store's lifetime, so pipelined window
    interleaving (begin i+1 before finish i) needs no special casing.  The model is an
-   upper bound: an engine may merge buckets or skip host-path work,
-   never dispatch more.
+   upper bound: an engine may merge buckets, never dispatch more.  Every
+   chunk of a put window is hashed on the device (the engine's SHA-1
+   cap covers the store's largest ``chunk_max``), so the sha1 budget
+   counts all of them.
 
 3. **Piece-ledger conservation** — after every put window and repair
    drain: each ``(chunk, cluster)`` index record's refcount equals the

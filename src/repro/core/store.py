@@ -256,7 +256,10 @@ class SEARSStore:
         self.latency = latency or LatencyParams()
         self.rng = np.random.default_rng(seed)
         self.hash_fn = hash_fn
-        self.engine = make_engine(engine, hash_fn)
+        # every chunk of every class is hashed on the device
+        self.engine = make_engine(
+            engine, hash_fn,
+            max_hash_len=max(c.chunk_max for c in class_list))
         self.repair = RepairManager(self, sub_batch=self.REPAIR_BATCH,
                                     bandwidth=repair_bandwidth)
         self._logical = {c.name: 0 for c in class_list}

@@ -5,14 +5,21 @@ The sequential gear recurrence is a linear recurrence, so the hash is a
 
     h[t] = sum_{j=0..31} 2^j * gear[byte[t-j]]   (mod 2^32)
 
-Each grid cell computes TILE outputs from TILE + 31 input bytes.  Pallas
-BlockSpecs cannot express halos directly, so the kernel receives the data
-*twice* with shifted index maps -- the current tile and the previous tile
--- and assembles the 31-byte halo from the previous tile's tail (masked to
-zero for the first tile, matching the reference's implicit zero-history).
+The stream is laid out (rows, 128) -- byte t at row t // 128, lane
+t % 128 -- and each grid cell computes the hashes of ROWS rows (TILE
+bytes).  Pallas BlockSpecs cannot express halos directly, so the kernel
+receives the data *twice* with shifted index maps -- the current tile and
+the block of rows before it -- and takes its history from that block's
+tail (zeroed for the first tile, matching the reference's implicit
+zero-history).
 
-The gear-table lookup is a 256-entry VMEM gather (``jnp.take``); the
-shifted accumulation is 32 vector adds on uint32 lanes (VPU).
+The gear-table lookup is a lane gather: the 256-entry table is held as
+two 128-lane rows and each byte picks its lane from the row its top bit
+selects (Mosaic lowers this 2-D, same-shape gather, not a 1-D
+``jnp.take``).  The window sum is built by doubling (a 2-tap sum, then
+4, ..., 32) with lane and sublane rotations, so no slice is unaligned.
+All of it runs in VMEM: HBM traffic is the tile and its history block
+read (TILE + HALO_BLOCK * 128 bytes per cell) plus the output.
 """
 
 from __future__ import annotations
@@ -23,19 +30,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.chunking import GEAR_TABLE, WINDOW
 from repro.kernels.launches import TRACES
 
 TILE = 8192  # output bytes per grid cell
-
-_GEAR_I32 = GEAR_TABLE.view(np.int32)  # bit-identical reinterpret
+LANES = 128  # stream bytes per row
+ROWS = TILE // LANES  # rows per grid cell
+HALO_BLOCK = 32  # rows of the history block (the uint8 sublane tile)
+HALO_ROWS = 8  # of which the kernel looks up the last (>= 31 bytes)
+# bit-identical int32 reinterpret, split into the table's two lane rows
+_GEAR_ROWS = GEAR_TABLE.view(np.int32).reshape(2, LANES)
 
 
 @functools.lru_cache(maxsize=1)
 def _device_gear_table() -> jnp.ndarray:
-    """Device-resident gear table, uploaded once per process."""
-    return jnp.asarray(_GEAR_I32).view(jnp.uint32)
+    """Device-resident (2, 128) gear table, uploaded once per process."""
+    return jnp.asarray(_GEAR_ROWS)
 
 
 def bucket_len(n: int) -> int:
@@ -69,24 +81,56 @@ def _kernel(cur_ref, prev_ref, gear_ref, out_ref):
     out_ref[...] = _hash_tile(pl.program_id(0), cur_ref, prev_ref, gear_ref)
 
 
+def _lookup(gear, b):
+    """gear[b] for (rows, LANES) int32 bytes b, as uint32.
+
+    Each byte's low 7 bits index a lane of the (2, LANES) table; its top
+    bit chooses the row.  Both gathers are ``take_along_axis`` on equal
+    (rows, LANES) shapes, the form Mosaic lowers to a lane gather.
+    """
+    rows = b.shape[0]
+    lane = b & (LANES - 1)
+    lo = jnp.take_along_axis(jnp.broadcast_to(gear[0:1], (rows, LANES)),
+                             lane, axis=1)
+    hi = jnp.take_along_axis(jnp.broadcast_to(gear[1:2], (rows, LANES)),
+                             lane, axis=1)
+    return jnp.where(b >= LANES, hi, lo).astype(jnp.uint32)
+
+
+def _shift(a, s: int):
+    """``a`` moved ``s`` positions later along the row-major stream.
+
+    out[r, l] = a[r, l - s], taking lanes below ``s`` from the end of row
+    r - 1 (row 0 wraps around and must not be used).
+    """
+    moved = pltpu.roll(a, s, axis=1)  # lanes l - s, same row
+    above = pltpu.roll(moved, 1, axis=0)  # the same, one row up
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    return jnp.where(lane >= s, moved, above)
+
+
 def _hash_tile(p, cur_ref, prev_ref, gear_ref):
-    """Shared kernel body: the (TILE,) gear hashes of grid cell ``p``."""
-    halo = WINDOW - 1
-    gear = gear_ref[...]  # (256,) uint32 (as int32 bits)
-    cur = cur_ref[...].astype(jnp.int32)  # (TILE,)
-    prev_tail = prev_ref[...][-halo:].astype(jnp.int32)  # (31,)
+    """Shared kernel body: the (ROWS, LANES) gear hashes of grid cell ``p``.
 
-    g_cur = jnp.take(gear, cur).astype(jnp.uint32)
-    g_prev = jnp.take(gear, prev_tail).astype(jnp.uint32)
-    # first tile has no history: its halo contributes nothing
-    g_prev = jnp.where(p == 0, jnp.uint32(0), g_prev)
-
-    ext = jnp.concatenate([g_prev, g_cur])  # (TILE + 31,) gear values
-    h = jnp.zeros((TILE,), jnp.uint32)
-    for j in range(WINDOW):
-        h = h + (jax.lax.dynamic_slice(ext, (halo - j,), (TILE,))
-                 << jnp.uint32(j))
-    return h
+    The 32-tap sum is built by doubling: after the step of shift s each
+    position holds the weighted sum of its last 2s gear values, so five
+    shifts stand for the 32 taps.
+    """
+    gear = gear_ref[...]  # (2, LANES) int32: table entries 0-127, 128-255
+    g = _lookup(gear, cur_ref[...].astype(jnp.int32))  # (ROWS, LANES)
+    # history: the previous tile's last HALO_ROWS rows; only their last
+    # 31 positions reach this tile, and what the doubling drags in from
+    # before them (or from the row-0 wrap) stays inside the history rows
+    prev = prev_ref[...].astype(jnp.int32)[-HALO_ROWS:]
+    hist = _lookup(gear, prev)
+    # first tile has no history: it contributes nothing
+    hist = jnp.where(p == 0, jnp.uint32(0), hist)
+    a = jnp.concatenate([hist, g])  # (HALO_ROWS + ROWS, LANES)
+    s = 1
+    while s < WINDOW:
+        a = a + (_shift(a, s) << jnp.uint32(s))
+        s *= 2
+    return a[HALO_ROWS:]
 
 
 def _fire_kernel(cur_ref, prev_ref, gear_ref, mask_ref, out_ref):
@@ -100,29 +144,37 @@ def _fire_kernel(cur_ref, prev_ref, gear_ref, mask_ref, out_ref):
     out_ref[...] = (h & mask_ref[...][0]) == 0
 
 
+def _stream_specs():
+    """BlockSpecs of the (rows, LANES) stream, its history block and the
+    table; the history block of cell 0 is clamped to block 0 and masked."""
+    per_tile = ROWS // HALO_BLOCK
+    return [
+        pl.BlockSpec((ROWS, LANES), lambda p: (p, 0)),
+        pl.BlockSpec((HALO_BLOCK, LANES),
+                     lambda p: (jnp.maximum(p * per_tile - 1, 0), 0)),
+        pl.BlockSpec((2, LANES), lambda p: (0, 0)),
+    ]
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _gear_fire_padded(data: jnp.ndarray, gear: jnp.ndarray,
-                      mask: jnp.ndarray,
-                      interpret: bool = True) -> jnp.ndarray:
+                      mask: jnp.ndarray, *,
+                      interpret: bool) -> jnp.ndarray:
     TRACES.gear += 1  # trace-time only: one increment per compiled shape
     n = data.shape[0]
-    grid = (n // TILE,)
+    rows = data.reshape(-1, LANES)
     return pl.pallas_call(
         _fire_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE,), lambda p: (p,)),
-            pl.BlockSpec((TILE,), lambda p: (jnp.maximum(p - 1, 0),)),
-            pl.BlockSpec((256,), lambda p: (0,)),
-            pl.BlockSpec((1,), lambda p: (0,)),
-        ],
-        out_specs=pl.BlockSpec((TILE,), lambda p: (p,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
+        grid=(n // TILE,),
+        in_specs=[*_stream_specs(),
+                  pl.BlockSpec((1,), lambda p: (0,))],
+        out_specs=pl.BlockSpec((ROWS, LANES), lambda p: (p, 0)),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.bool_),
         interpret=interpret,
-    )(data, data, gear, mask)
+    )(rows, rows, gear, mask).reshape(n)
 
 
-def gear_fire(data, mask, interpret: bool = True) -> jnp.ndarray:
+def gear_fire(data, mask, *, interpret: bool) -> jnp.ndarray:
     """(N,) uint8 + boundary mask -> (N,) bool fire bitmap (one launch).
 
     The fused twin of :func:`gear_hash`: hash and mask test both run on
@@ -141,26 +193,22 @@ def gear_fire(data, mask, interpret: bool = True) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _gear_hash_padded(data: jnp.ndarray, gear: jnp.ndarray,
-                      interpret: bool = True) -> jnp.ndarray:
+def _gear_hash_padded(data: jnp.ndarray, gear: jnp.ndarray, *,
+                      interpret: bool) -> jnp.ndarray:
     TRACES.gear += 1  # trace-time only: one increment per compiled shape
     n = data.shape[0]
-    grid = (n // TILE,)
+    rows = data.reshape(-1, LANES)
     return pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((TILE,), lambda p: (p,)),
-            pl.BlockSpec((TILE,), lambda p: (jnp.maximum(p - 1, 0),)),
-            pl.BlockSpec((256,), lambda p: (0,)),
-        ],
-        out_specs=pl.BlockSpec((TILE,), lambda p: (p,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.uint32),
+        grid=(n // TILE,),
+        in_specs=_stream_specs(),
+        out_specs=pl.BlockSpec((ROWS, LANES), lambda p: (p, 0)),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.uint32),
         interpret=interpret,
-    )(data, data, gear)
+    )(rows, rows, gear).reshape(n)
 
 
-def gear_hash(data, interpret: bool = True) -> jnp.ndarray:
+def gear_hash(data, *, interpret: bool) -> jnp.ndarray:
     """(N,) uint8 -> (N,) uint32 gear hash (kernel entry point).
 
     Input is zero-padded to ``bucket_len(n)`` so repeated calls with

@@ -47,8 +47,8 @@ def _kernel(gbits_ref, d_ref, out_ref, *, k: int, r: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _gf_matmul_padded(gbits: jnp.ndarray, data: jnp.ndarray,
-                      interpret: bool = True) -> jnp.ndarray:
+def _gf_matmul_padded(gbits: jnp.ndarray, data: jnp.ndarray, *,
+                      interpret: bool) -> jnp.ndarray:
     """gbits: (8r, 8k) f32; data: (B, k, L) uint8 with L % TILE_L == 0."""
     TRACES.gf += 1  # trace-time only: one increment per compiled shape
     B, k, L = data.shape
@@ -80,8 +80,8 @@ def _gbits_cached(mbytes: bytes, r: int, k: int) -> jnp.ndarray:
     return jnp.asarray(gf256.gf_matrix_to_bits(M), dtype=jnp.float32)
 
 
-def gf_matmul(M: np.ndarray, data: jnp.ndarray,
-              interpret: bool = True) -> jnp.ndarray:
+def gf_matmul(M: np.ndarray, data: jnp.ndarray, *,
+              interpret: bool) -> jnp.ndarray:
     """Apply an (r,k) GF(256) coding matrix to (B, k, L) uint8 pieces.
 
     Returns (B, r, L) uint8.  ``M`` must be a host numpy matrix (it is

@@ -1,12 +1,11 @@
 """Public jit'd entry points for the SEARS compute kernels.
 
 Dispatch policy: on TPU backends the Pallas kernels run compiled
-(``interpret=False``); everywhere else (this CPU container, tests) they run
-in interpret mode, which executes the same kernel body in Python for
+(``interpret=False``); on any other backend (CPU test runs) they run in
+interpret mode, which executes the same kernel body in Python for
 correctness.  ``impl='ref'`` selects the pure-jnp oracle -- useful both for
-differential testing and as an XLA-fusible fallback (and the default data
-plane off-TPU, where interpret mode is Python-slow; see
-``engine.KernelEngine``).
+differential testing and as the default data plane off-TPU, where
+interpret mode is Python-slow (see ``engine.KernelEngine``).
 
 Every entry point here is launch-cached: the jitted callables are module
 level (so XLA's compile cache keys on shape alone, never on call site) and
@@ -19,6 +18,8 @@ layers (``core.scheduler``, benchmarks) can prove launch amortization.
 from __future__ import annotations
 
 import functools
+import os
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,26 @@ from repro.kernels import flash_attn, gear_cdc, gf_matmul, ref, sha1
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+# the checkout holding this package (src/repro/kernels/ops.py)
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compile cache in a fixed place; return it.
+
+    JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself, so when that is set
+    nothing else is set here.  Otherwise the cache is ``.jax_cache`` at
+    the root of the checkout (git-ignored): one fixed path, so a later
+    process run from the same checkout finds what an earlier one compiled.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ------------------------------------------------------- launch counting ---
@@ -249,24 +270,40 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 # ------------------------------------------------------------------ sha1 ---
 def _sha1_words_loop(blocks: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
-    """SHA-1 oracle body: ``fori_loop`` over blocks, not unrolled.
+    """SHA-1 oracle body: ``fori_loop`` over blocks and over the 80 rounds.
 
-    Semantically identical to ``ref.sha1_ref`` but traces the 80-round
-    compression once regardless of the padded block count, so a bucketed
-    (B, M, 16) launch compiles in O(1) and is reused for every
-    subsequent batch.  Shared by the standalone jitted entry point and
-    the fused ingest launch (which runs it in the same residency as the
-    GF encode).
+    Semantically identical to ``ref.sha1_ref``, but the traced body is one
+    round, so a bucketed (B, M, 16) launch compiles in O(1) and is reused
+    for every subsequent batch.  Looping the rounds as well matters on
+    XLA's CPU backend: an unrolled 80-round body ran as hundreds of small
+    ops per block (a (64, 129, 16) batch took 16 s on a CPU host, the
+    round loop 0.06 s).  Messages lie along the last axis, (16, B) words
+    per block.  Shared by the standalone jitted entry point and the fused
+    ingest launch (which runs it in the same residency as the GF encode).
     """
-    B, M, _ = blocks.shape
+    B = blocks.shape[0]
+    words = blocks.transpose(1, 2, 0)  # (M, 16, B)
     h0 = jnp.broadcast_to(jnp.asarray(hashing.SHA1_H0.astype(np.int64),
-                                      jnp.uint32), (B, 5))
+                                      jnp.uint32)[:, None], (5, B))
 
-    def body(m, h):
-        upd = ref._sha1_block(h, blocks[:, m, :])
-        return jnp.where((m < counts)[:, None], upd, h)
+    def block(m, h):
+        def round_(t, st):
+            a, b, c, d, e, w = st
+            i = t % 16  # w holds the last 16 schedule words
+            expanded = ref._rotl(w[(t - 3) % 16] ^ w[(t - 8) % 16]
+                                 ^ w[(t - 14) % 16] ^ w[i], 1)
+            wt = jnp.where(t < 16, w[i], expanded)
+            q = t // 20
+            f = jnp.where(q == 0, (b & c) | (~b & d),
+                          jnp.where(q == 2, (b & c) | (b & d) | (c & d),
+                                    b ^ c ^ d))
+            tmp = ref._rotl(a, 5) + f + e + ref._K[q] + wt
+            return tmp, a, ref._rotl(b, 30), c, d, w.at[i].set(wt)
 
-    return jax.lax.fori_loop(0, M, body, h0)
+        *out, _ = jax.lax.fori_loop(0, 80, round_, (*h, words[m]))
+        return jnp.where(m < counts, h + jnp.stack(out), h)
+
+    return jax.lax.fori_loop(0, words.shape[0], block, h0).T
 
 
 def _sha1_ref_body(blocks: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
@@ -318,8 +355,8 @@ def _fused_ingest_ref(Mdev: jnp.ndarray, blocks: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _fused_ingest_pallas(gbits: jnp.ndarray, blocks: jnp.ndarray,
-                         counts: jnp.ndarray, data: jnp.ndarray,
-                         interpret: bool = True):
+                         counts: jnp.ndarray, data: jnp.ndarray, *,
+                         interpret: bool):
     """Fused Pallas path: both kernels issued under one jit (one residency)."""
     TRACES.fused += 1  # trace-time only: one increment per compiled shape
     return (sha1.sha1_digest_words(blocks, counts, interpret=interpret),

@@ -1,11 +1,12 @@
 """Batched SHA-1 Pallas kernel.
 
 SHA-1 is sequential over the 64-byte blocks of one message but fully
-parallel across messages, so the TPU mapping is lane-parallel: each grid
-cell processes TILE_B messages; the 80-round compression runs unrolled on
-(TILE_B,)-wide uint32 vectors (VPU logical/rotate/add ops) and a
-``fori_loop`` walks the message blocks.  Messages shorter than the padded
-block count are masked per-lane via ``counts``.
+parallel across messages, so the TPU mapping is lane-parallel: the
+message axis is laid out last -- (M, 16, B) words, one message per lane --
+and each grid cell processes TILE_B messages; the 80-round compression
+runs unrolled on (TILE_B,)-wide uint32 rows (VPU logical/rotate/add ops)
+and a ``fori_loop`` walks the message blocks.  Messages shorter than the
+padded block count are masked per-lane via ``counts``.
 
 Input comes from :func:`repro.core.hashing.sha1_pad_batch` (standard SHA-1
 padding done host-side); output digests match ``hashlib.sha1`` bit-exactly.
@@ -34,8 +35,8 @@ def _rotl(x, c):
 
 
 def _compress(h, words):
-    """h: 5-tuple of (TILE_B,) uint32; words: (TILE_B, 16) uint32."""
-    w = [words[:, t] for t in range(16)]
+    """h: 5-tuple of (TILE_B,) uint32; words: (16, TILE_B) uint32."""
+    w = [words[t] for t in range(16)]
     for t in range(16, 80):
         w.append(_rotl(w[t - 3] ^ w[t - 8] ^ w[t - 14] ^ w[t - 16], 1))
     a, b, c, d, e = h
@@ -54,40 +55,41 @@ def _compress(h, words):
 
 
 def _kernel(blocks_ref, counts_ref, out_ref, *, n_blocks: int):
-    counts = counts_ref[...][:, 0]  # (TILE_B,)
+    counts = counts_ref[0]  # (TILE_B,)
     h0 = tuple(jnp.full((counts.shape[0],), jnp.uint32(_H0[i]))
                for i in range(5))
 
     def body(m, h):
-        words = blocks_ref[:, m, :].astype(jnp.uint32)
+        words = blocks_ref[m]  # (16, TILE_B)
         upd = _compress(h, words)
         live = m < counts
         return tuple(jnp.where(live, u, x) for u, x in zip(upd, h))
 
     h = jax.lax.fori_loop(0, n_blocks, body, h0)
-    out_ref[...] = jnp.stack(h, axis=-1)
+    out_ref[...] = jnp.stack(h)  # (5, TILE_B)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile"))
-def _sha1_padded(blocks: jnp.ndarray, counts: jnp.ndarray,
-                 interpret: bool = True, tile: int = TILE_B) -> jnp.ndarray:
+def _sha1_padded(blocks: jnp.ndarray, counts: jnp.ndarray, *,
+                 interpret: bool, tile: int = TILE_B) -> jnp.ndarray:
     TRACES.sha1 += 1  # trace-time only: one increment per compiled shape
     B, M, _ = blocks.shape
-    grid = (B // tile,)
+    # messages on lanes: laid out messages-first, (B, M, 16), a window-
+    # scale batch (4096 x 81 blocks) ran out of scoped VMEM on a v5e
     return pl.pallas_call(
         functools.partial(_kernel, n_blocks=M),
-        grid=grid,
+        grid=(B // tile,),
         in_specs=[
-            pl.BlockSpec((tile, M, 16), lambda b: (b, 0, 0)),
-            pl.BlockSpec((tile, 1), lambda b: (b, 0)),
+            pl.BlockSpec((M, 16, tile), lambda b: (0, 0, b)),
+            pl.BlockSpec((1, tile), lambda b: (0, b)),
         ],
-        out_specs=pl.BlockSpec((tile, 5), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 5), jnp.uint32),
+        out_specs=pl.BlockSpec((5, tile), lambda b: (0, b)),
+        out_shape=jax.ShapeDtypeStruct((5, B), jnp.uint32),
         interpret=interpret,
-    )(blocks, counts)
+    )(blocks.transpose(1, 2, 0), counts.reshape(1, B)).T
 
 
-def sha1_digest_words(blocks, counts, interpret: bool = True) -> jnp.ndarray:
+def sha1_digest_words(blocks, counts, *, interpret: bool) -> jnp.ndarray:
     """(B, M, 16) uint32 padded blocks + (B,) counts -> (B, 5) digests.
 
     Batches of at least TILE_B messages pad to a TILE_B multiple and run

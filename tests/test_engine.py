@@ -95,7 +95,8 @@ def test_kernel_engine_hash_launch_shapes_stay_bucketed(monkeypatch):
     clamped to blocks(max_hash_len)), so small windows stop paying the
     worst-case width.  A chunk longer than ``max_hash_len`` used to
     silently grow the block axis (``sha1_pad_batch`` took ``max`` of the
-    cap and the batch's own need); now it takes the host fallback.
+    cap and the batch's own need); now it raises instead of launching a
+    wider shape or being hashed on the host.
     """
     from repro.kernels import ops
 
@@ -109,12 +110,14 @@ def test_kernel_engine_hash_launch_shapes_stay_bucketed(monkeypatch):
         return real(blocks, counts, impl=impl)
 
     monkeypatch.setattr(ops, "sha1_digest_words", spy)
-    chunks = [_data(100, seed=1), _data(5000, seed=2),  # 5000 > max_hash_len
-              _data(1024, seed=3), _data(0, seed=4), _data(30_000, seed=5)]
+    chunks = [_data(100, seed=1), _data(1024, seed=3), _data(0, seed=4)]
     digests = eng.hash_chunks(chunks)
     assert digests == [hashlib.sha1(c).digest() for c in chunks]
     # one launch: 3 in-cap chunks pad to batch 4 (pow2), 17 blocks (cap)
     assert seen_shapes == [(4, fixed_blocks, 16)]
+    with pytest.raises(ValueError, match="max_len"):  # 5000 > max_hash_len
+        eng.hash_chunks(chunks + [_data(5000, seed=2)])
+    assert seen_shapes == [(4, fixed_blocks, 16)]  # nothing launched
 
 
 def test_sha1_pad_batch_max_len_is_authoritative():
@@ -127,7 +130,7 @@ def test_sha1_pad_batch_max_len_is_authoritative():
     assert blocks.shape == (1, 4, 16)  # 4-block need: pow2 bucket
     blocks, _ = hashing.sha1_pad_batch([b"x" * 1024], max_len=1024)
     assert blocks.shape == (1, 17, 16)  # pow2(17)=32 clamps to the cap
-    with pytest.raises(ValueError, match="host"):
+    with pytest.raises(ValueError, match="max_len"):
         hashing.sha1_pad_batch([b"x" * 5000], max_len=1024)
 
 
@@ -141,6 +144,10 @@ def test_make_engine_specs():
     assert make_engine(eng) is eng
     with pytest.raises(ValueError):
         make_engine("vax")
+    # the SHA-1 cap follows the classes; a given engine may not be short
+    assert make_engine("kernel", max_hash_len=16384).max_hash_len == 16384
+    with pytest.raises(ValueError, match="16384"):
+        make_engine(KernelEngine(), max_hash_len=16384)
 
 
 # ------------------------------------------------------- differential ------
@@ -204,3 +211,25 @@ def test_engines_differential_multi_user():
         o_np, _ = stores["numpy"].get_file(user, fn)
         o_kn, _ = stores["kernel"].get_file(user, fn)
         assert o_np == o_kn == blob
+
+
+def test_compile_cache_dir_is_fixed_or_from_env(monkeypatch):
+    """``use_compile_cache`` honours the env var, else one in-checkout path."""
+    import pathlib
+
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert ops.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before  # set nothing
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = ops.use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == first
+        assert ops.use_compile_cache() == first  # same path every call
+        checkout = pathlib.Path(ops.__file__).resolve().parents[3]
+        assert pathlib.Path(first) == checkout / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

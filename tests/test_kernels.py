@@ -90,6 +90,25 @@ def test_gear_ref_vs_sequential_oracle():
                                   gear_hash_sequential(data))
 
 
+@pytest.mark.parametrize("n", [2 * 8192 + 100, 3 * 8192])
+def test_gear_kernel_pinned_to_refs(n):
+    """The compiled-path gear body (lane-gather lookup, static taps) in
+    interpret mode equals the jnp oracle and the host candidate scan over
+    2-3 tiles: first-tile zero halo, tile seams, and bucket padding."""
+    from repro.core.chunking import gear_candidates_np
+    from repro.kernels import gear_cdc
+
+    data = np.random.RandomState(n).randint(  # noqa: NPY002
+        0, 256, size=n, dtype=np.uint8)
+    h = np.asarray(gear_cdc.gear_hash(data, interpret=True))
+    np.testing.assert_array_equal(h, np.asarray(ref.gear_hash_ref(data)))
+    np.testing.assert_array_equal(h[:40], gear_hash_sequential(data[:40]))
+    mask = np.uint32((1 << 9) - 1)
+    fire = np.asarray(gear_cdc.gear_fire(data, mask, interpret=True))
+    np.testing.assert_array_equal(np.flatnonzero(fire),
+                                  gear_candidates_np(data, mask))
+
+
 @pytest.mark.slow
 def test_gear_kernel_tile_boundary_exactness():
     # values spanning the 8192-byte tile boundary depend on the halo

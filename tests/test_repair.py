@@ -5,8 +5,8 @@ Four contract families for ``repro.core.repair.RepairManager``:
 * **storm recovery (differential)** -- after any seeded kill / revive /
   replace / repair schedule from ``workload.failure_storm_trace``, every
   file whose referenced chunks kept >= k surviving pieces reads back
-  byte-identical, on both engines (hypothesis property where installed,
-  seeded-loop fallback otherwise, per ``tests/conftest.py``).
+  byte-identical, on both engines (a hypothesis property plus a seeded
+  loop).
 * **accounting** -- a repair pass never aborts: every chunk copy lands in
   exactly one of rebuilt / skipped-healthy / unrecoverable, and the piece
   ledger balances (``pieces_missing == rebuilt + failed + unrecoverable``).
