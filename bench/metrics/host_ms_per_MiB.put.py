@@ -1,0 +1,12 @@
+"""Host milliseconds per logical MiB put outside the engine: flush() wall
+time minus the time inside the engine's data-plane calls."""
+
+KIND = "put_rounds"
+
+
+def read(ctx):
+    w, inst = ctx.window, ctx.instrument
+    nbytes = w.put_bytes if KIND == "put_rounds" else w.get_bytes
+    if inst is None or ctx.kind != KIND or not nbytes:
+        return None
+    return 1e3 * (inst.flush_s - inst.engine_s) / (nbytes / 2**20)
