@@ -1,0 +1,15 @@
+"""Host milliseconds per logical MiB of gets spent dispatching the kernels,
+host-to-device copy included (span ``sears.engine.dispatch``): the
+window's ``SchedulerStats`` ``engine_dispatch_s``. A program without the
+field reads nothing."""
+
+KIND = "open_get"
+FIELDS = ("engine_dispatch_s",)
+
+
+def read(ctx):
+    w, d = ctx.window, ctx.sched_delta
+    nbytes = w.put_bytes if KIND == "put_rounds" else w.get_bytes
+    if ctx.kind != KIND or not nbytes or any(f not in d for f in FIELDS):
+        return None
+    return 1e3 * sum(d[f] for f in FIELDS) / (nbytes / 2**20)

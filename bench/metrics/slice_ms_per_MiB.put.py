@@ -1,0 +1,15 @@
+"""Host milliseconds per logical MiB put spent slicing each file's chunks
+out into ``bytes`` (span ``sears.put.slice``): the window's
+``SchedulerStats`` ``put_slice_s``. A program without the field reads
+nothing."""
+
+KIND = "put_rounds"
+FIELDS = ("put_slice_s",)
+
+
+def read(ctx):
+    w, d = ctx.window, ctx.sched_delta
+    nbytes = w.put_bytes if KIND == "put_rounds" else w.get_bytes
+    if ctx.kind != KIND or not nbytes or any(f not in d for f in FIELDS):
+        return None
+    return 1e3 * sum(d[f] for f in FIELDS) / (nbytes / 2**20)

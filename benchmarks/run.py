@@ -1,4 +1,4 @@
-"""Benchmark driver: one module per paper table/figure + kernels + roofline.
+"""Benchmark runner: one module per paper table/figure + kernels.
 
 Prints ``name,us_per_call,derived`` CSV rows (per the harness contract)
 and writes the full JSON to results/bench.json.  Each module also ships a

@@ -30,6 +30,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.kernels.launches import span
+
 GEAR_SEED = 0x5EA125  # fixed so chunk ids are stable across runs/hosts
 _rng = np.random.RandomState(GEAR_SEED)
 GEAR_TABLE = _rng.randint(0, 2**32, size=256, dtype=np.uint64).astype(np.uint32)
@@ -203,14 +205,15 @@ def chunk_spans_batch_begin(chunker: Chunker, blobs: list[np.ndarray],
     double-buffering seam: window *i+1*'s gear launch runs while window
     *i*'s host phases (selection, dedup planning) execute.
     """
-    blobs = [as_bytes_array(b) for b in blobs]
-    lengths = np.array([b.shape[0] for b in blobs], dtype=np.int64)
-    if int(lengths.sum()) == 0:
-        return PendingSpans(chunker=chunker, lengths=lengths,
-                            starts=np.zeros_like(lengths), stream=None,
-                            handle=None)
-    starts = np.concatenate([[0], np.cumsum(lengths)[:-1]])
-    stream = np.concatenate([b for b in blobs if b.shape[0]])
+    with span("sears.engine.pack"):
+        blobs = [as_bytes_array(b) for b in blobs]
+        lengths = np.array([b.shape[0] for b in blobs], dtype=np.int64)
+        if int(lengths.sum()) == 0:
+            return PendingSpans(chunker=chunker, lengths=lengths,
+                                starts=np.zeros_like(lengths), stream=None,
+                                handle=None)
+        starts = np.concatenate([[0], np.cumsum(lengths)[:-1]])
+        stream = np.concatenate([b for b in blobs if b.shape[0]])
     return PendingSpans(chunker=chunker, lengths=lengths, starts=starts,
                         stream=stream,
                         handle=issue_fn(stream, chunker.mask))
@@ -234,33 +237,35 @@ def chunk_spans_batch_finish(pending: PendingSpans, resolve_fn
 
     halo = WINDOW - 1
     spans: list[list[tuple[int, int]]] = []
-    for start, n in zip(starts, lengths):
-        start, n = int(start), int(n)
-        if n == 0:
-            spans.append([])
-            continue
-        # uncontaminated candidates: local offset >= halo
-        lo = int(np.searchsorted(fire, start + halo, side="left"))
-        hi = int(np.searchsorted(fire, start + n, side="left"))
-        cand = fire[lo:hi] - start + 1  # cut *after* byte t
-        if halo and start > 0:
-            # head positions see the previous file's tail in the shared
-            # stream; redo them from the file's own (zero-history) head
-            head = chunker.candidates(stream[start:start + min(halo, n)])
-            if head.size:
-                cand = np.concatenate([head.astype(np.int64), cand])
-        elif halo:
-            # first file: the stream head *is* its head, keep exact cands
-            head_lo = int(np.searchsorted(fire, start, side="left"))
-            head = fire[head_lo:lo] - start + 1
-            if head.size:
-                cand = np.concatenate([head, cand])
-        cuts = select_boundaries(cand, n, chunker.min_size, chunker.max_size)
-        out, prev = [], 0
-        for c in cuts:
-            out.append((prev, int(c) - prev))
-            prev = int(c)
-        spans.append(out)
+    with span("sears.engine.unpack"):
+        for start, n in zip(starts, lengths):
+            start, n = int(start), int(n)
+            if n == 0:
+                spans.append([])
+                continue
+            # uncontaminated candidates: local offset >= halo
+            lo = int(np.searchsorted(fire, start + halo, side="left"))
+            hi = int(np.searchsorted(fire, start + n, side="left"))
+            cand = fire[lo:hi] - start + 1  # cut *after* byte t
+            if halo and start > 0:
+                # head positions see the previous file's tail in the shared
+                # stream; redo them from the file's own (zero-history) head
+                head = chunker.candidates(stream[start:start + min(halo, n)])
+                if head.size:
+                    cand = np.concatenate([head.astype(np.int64), cand])
+            elif halo:
+                # first file: the stream head *is* its head, keep exact cands
+                head_lo = int(np.searchsorted(fire, start, side="left"))
+                head = fire[head_lo:lo] - start + 1
+                if head.size:
+                    cand = np.concatenate([head, cand])
+            cuts = select_boundaries(cand, n, chunker.min_size,
+                                     chunker.max_size)
+            out, prev = [], 0
+            for c in cuts:
+                out.append((prev, int(c) - prev))
+                prev = int(c)
+            spans.append(out)
     return spans
 
 

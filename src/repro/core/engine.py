@@ -25,11 +25,10 @@ from __future__ import annotations
 
 import abc
 
-import numpy as np
-
 from repro.core import chunking, hashing
 from repro.core.chunking import Chunker
 from repro.core.rs_code import RSCode
+from repro.kernels.launches import span, to_host
 
 
 class CodingEngine(abc.ABC):
@@ -312,21 +311,25 @@ class KernelEngine(CodingEngine):
         out: list[bytes] = []
         for i in range(0, len(chunks), self.hash_batch):
             group = chunks[i: i + self.hash_batch]
-            # pad the batch axis to the next power of two (clamped to
-            # hash_batch): a steady-state window of tens of chunks no
-            # longer drags hash_batch-wide dead lanes through the
-            # compression loop, and the compiled-shape set stays bounded
-            # ({1, 2, 4, ..., hash_batch} x bucketed block widths)
-            target = min(1 << max(0, len(group) - 1).bit_length(),
-                         self.hash_batch)
-            pad = target - len(group)
-            # raises ValueError for a chunk over max_hash_len: growing the
-            # compiled block axis, or hashing it on the host, would hide it
-            blocks, counts = hashing.sha1_pad_batch(
-                group + [b""] * pad, max_len=self.max_hash_len)
-            words = ops.sha1_digest_words(blocks, counts, impl=self.impl)
-            words = np.asarray(words)[:len(group)]
-            out += hashing.digest_words_to_bytes(words)
+            with span("sears.engine.pack"):
+                # pad the batch axis to the next power of two (clamped to
+                # hash_batch): a steady-state window of tens of chunks no
+                # longer drags hash_batch-wide dead lanes through the
+                # compression loop, and the compiled-shape set stays
+                # bounded ({1, 2, 4, ..., hash_batch} x bucketed block
+                # widths)
+                target = min(1 << max(0, len(group) - 1).bit_length(),
+                             self.hash_batch)
+                pad = target - len(group)
+                # raises ValueError for a chunk over max_hash_len: growing
+                # the compiled block axis, or hashing it on the host,
+                # would hide it
+                blocks, counts = hashing.sha1_pad_batch(
+                    group + [b""] * pad, max_len=self.max_hash_len)
+            words = to_host(ops.sha1_digest_words(blocks, counts,
+                                                  impl=self.impl))
+            with span("sears.engine.unpack"):
+                out += hashing.digest_words_to_bytes(words[:len(group)])
         return out
 
     def encode_blobs(self, code: RSCode,

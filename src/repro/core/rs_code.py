@@ -20,6 +20,7 @@ import functools
 import numpy as np
 
 from repro.core import gf256
+from repro.kernels.launches import span, to_host
 
 
 @functools.lru_cache(maxsize=None)
@@ -128,17 +129,22 @@ def batch_encode_blobs(code: "RSCode", blobs: list[bytes], apply_fn,
     returns (B, r, Lp); ``pad_batch`` rounds the batch axis up (e.g. to a
     power of two to bound compiled kernel shapes).
     """
-    piece_lens = [code.piece_len(len(b)) for b in blobs]
+    with span("sears.engine.pack"):
+        piece_lens = [code.piece_len(len(b)) for b in blobs]
+        buckets = bucket_by_piece_len(piece_lens, quantum)
     out: list[list[bytes] | None] = [None] * len(blobs)
     G = generator_matrix(code.n, code.k)
-    for Lp, idxs in bucket_by_piece_len(piece_lens, quantum).items():
-        arr = np.zeros((pad_batch(len(idxs)), code.k, Lp), dtype=np.uint8)
-        for row, i in enumerate(idxs):
-            arr[row] = pack_blob(blobs[i], code.k, piece_lens[i], Lp)
-        enc = np.asarray(apply_fn(G, arr))  # (Bp, n, Lp)
-        for row, i in enumerate(idxs):
-            L = piece_lens[i]
-            out[i] = [enc[row, j, :L].tobytes() for j in range(code.n)]
+    for Lp, idxs in buckets.items():
+        with span("sears.engine.pack"):
+            arr = np.zeros((pad_batch(len(idxs)), code.k, Lp),
+                           dtype=np.uint8)
+            for row, i in enumerate(idxs):
+                arr[row] = pack_blob(blobs[i], code.k, piece_lens[i], Lp)
+        enc = to_host(apply_fn(G, arr))  # (Bp, n, Lp)
+        with span("sears.engine.unpack"):
+            for row, i in enumerate(idxs):
+                L = piece_lens[i]
+                out[i] = [enc[row, j, :L].tobytes() for j in range(code.n)]
     return out  # type: ignore[return-value]
 
 
@@ -162,26 +168,31 @@ def batch_decode_blobs_begin(code: "RSCode",
     nbytes_list: list[int] = []
     buckets: dict[tuple[tuple[int, ...], int], list[int]] = {}
     systematic = tuple(range(code.k))
-    for i, (pieces, nbytes) in enumerate(jobs):
-        if len(pieces) < code.k:
-            raise ValueError(
-                f"need >= k={code.k} pieces to decode, got {len(pieces)}")
-        idx = tuple(sorted(pieces)[: code.k])
-        L = code.piece_len(nbytes)
-        piece_lens.append(L)
-        nbytes_list.append(nbytes)
-        if idx == systematic:
-            if any(len(pieces[j]) != L for j in idx):
-                raise ValueError(f"piece shape mismatch: want piece_len {L}")
-            out[i] = b"".join(pieces[j] for j in idx)[:nbytes]
-            continue
-        buckets.setdefault((idx, padded_piece_len(L, quantum)), []).append(i)
+    with span("sears.engine.pack"):
+        for i, (pieces, nbytes) in enumerate(jobs):
+            if len(pieces) < code.k:
+                raise ValueError(
+                    f"need >= k={code.k} pieces to decode, got {len(pieces)}")
+            idx = tuple(sorted(pieces)[: code.k])
+            L = code.piece_len(nbytes)
+            piece_lens.append(L)
+            nbytes_list.append(nbytes)
+            if idx == systematic:
+                if any(len(pieces[j]) != L for j in idx):
+                    raise ValueError(
+                        f"piece shape mismatch: want piece_len {L}")
+                out[i] = b"".join(pieces[j] for j in idx)[:nbytes]
+                continue
+            buckets.setdefault((idx, padded_piece_len(L, quantum)),
+                               []).append(i)
     launched = []
     for (idx, Lp), idxs in buckets.items():
-        arr = np.zeros((pad_batch(len(idxs)), code.k, Lp), dtype=np.uint8)
-        for row, i in enumerate(idxs):
-            arr[row] = pack_pieces(jobs[i][0], idx, piece_lens[i], Lp)
-        M = decode_matrix(code.n, code.k, idx)
+        with span("sears.engine.pack"):
+            arr = np.zeros((pad_batch(len(idxs)), code.k, Lp),
+                           dtype=np.uint8)
+            for row, i in enumerate(idxs):
+                arr[row] = pack_pieces(jobs[i][0], idx, piece_lens[i], Lp)
+            M = decode_matrix(code.n, code.k, idx)
         launched.append((apply_fn(M, arr), idxs))  # (Bp, k, Lp) in flight
     return out, launched, piece_lens, nbytes_list
 
@@ -190,10 +201,11 @@ def batch_decode_blobs_finish(state) -> list[bytes]:
     """Materialize a ``batch_decode_blobs_begin`` state -> decoded blobs."""
     out, launched, piece_lens, nbytes_list = state
     for dec, idxs in launched:
-        dec = np.asarray(dec)  # blocks on the in-flight launch
-        for row, i in enumerate(idxs):
-            L, nbytes = piece_lens[i], nbytes_list[i]
-            out[i] = dec[row, :, :L].reshape(-1)[:nbytes].tobytes()
+        dec = to_host(dec)  # blocks on the in-flight launch
+        with span("sears.engine.unpack"):
+            for row, i in enumerate(idxs):
+                L, nbytes = piece_lens[i], nbytes_list[i]
+                out[i] = dec[row, :, :L].reshape(-1)[:nbytes].tobytes()
     return out  # type: ignore[return-value]
 
 

@@ -282,11 +282,53 @@ class SchedulerStats:
     # per-class admission control (lanes=True + queue limits)
     n_admission_shed: int = 0  # queued lower-priority requests withdrawn
     n_admission_rejected: int = 0  # incoming requests refused outright
+    # host seconds per step of the foreground windows (``sears.*`` spans
+    # of kernels.launches.SPANS, folded in per flush; see SPAN_FIELDS).
+    # Put window: the chunking pass, slicing chunks to bytes, chunk ids,
+    # dedup + placement planning, RS encode, piece writes.
+    put_chunk_s: float = 0.0
+    put_slice_s: float = 0.0
+    put_hash_s: float = 0.0
+    put_plan_s: float = 0.0
+    put_encode_s: float = 0.0
+    put_write_s: float = 0.0
+    # get window: planning, piece reads, read-repair hints (a census of
+    # each chunk read without its k data pieces), decode, assembly
+    get_plan_s: float = 0.0
+    get_read_s: float = 0.0
+    get_hint_s: float = 0.0
+    get_decode_s: float = 0.0
+    get_assemble_s: float = 0.0
+    # inside the engine, under whichever step called it: host packing,
+    # dispatch (with its host->device copy), waiting on the device (with
+    # the device->host copy), host unpacking
+    engine_pack_s: float = 0.0
+    engine_dispatch_s: float = 0.0
+    engine_wait_s: float = 0.0
+    engine_unpack_s: float = 0.0
+    # bytes the foreground windows moved (kernels.launches.TRANSFERS)
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
 
     @property
     def data_plane_launches(self) -> int:
         return (self.gf_launches + self.sha1_launches + self.gear_launches
                 + self.fused_launches)
+
+
+# the span each per-step ``SchedulerStats`` field sums
+SPAN_FIELDS = {
+    "sears.put.chunk": "put_chunk_s", "sears.put.slice": "put_slice_s",
+    "sears.put.hash": "put_hash_s", "sears.put.plan": "put_plan_s",
+    "sears.put.encode": "put_encode_s", "sears.put.write": "put_write_s",
+    "sears.get.plan": "get_plan_s", "sears.get.read": "get_read_s",
+    "sears.get.hint": "get_hint_s", "sears.get.decode": "get_decode_s",
+    "sears.get.assemble": "get_assemble_s",
+    "sears.engine.pack": "engine_pack_s",
+    "sears.engine.dispatch": "engine_dispatch_s",
+    "sears.engine.wait": "engine_wait_s",
+    "sears.engine.unpack": "engine_unpack_s",
+}
 
 
 class BatchScheduler:
@@ -546,7 +588,8 @@ class BatchScheduler:
         set) or ``failed`` (``error`` set) -- flush itself never raises on
         a per-request failure.
         """
-        from repro.kernels.launches import LAUNCHES  # dep-free counters
+        from repro.kernels.launches import (  # dep-free counters
+            delta_all, snapshot_all, span)
 
         requests = self.queue.drain()
         self._pending_bytes = 0
@@ -556,8 +599,7 @@ class BatchScheduler:
             self._repair_window()  # background scrub/repair/write-back
             self._writeback_window()
             return []
-        before = LAUNCHES.snapshot()
-        t0 = time.perf_counter()
+        before = snapshot_all()
         if self.lanes:
             # priority lanes: realtime preempts archival inside this
             # flush (stable sort -- within a class, submission order
@@ -565,6 +607,32 @@ class BatchScheduler:
             requests = sorted(requests,
                               key=lambda r: (self._priority(r),
                                              r.request_id))
+        with span("sears.flush", step=self.stats.n_flushes) as timed:
+            self._run_windows(requests)
+        delta = delta_all(before)
+        launches, spans = delta["launches"], delta["spans"]
+        self.stats.n_flushes += 1
+        self.stats.n_requests += len(requests)
+        self.stats.n_failed += sum(1 for r in requests if not r.ok)
+        self.stats.gf_launches += launches.gf
+        self.stats.sha1_launches += launches.sha1
+        self.stats.gear_launches += launches.gear
+        self.stats.fused_launches += launches.fused
+        self.stats.flush_seconds += timed.seconds
+        for name, secs in spans.seconds.items():
+            field = SPAN_FIELDS.get(name)
+            if field is not None:
+                setattr(self.stats, field,
+                        getattr(self.stats, field) + secs)
+        self.stats.h2d_bytes += delta["transfers"].h2d_bytes
+        self.stats.d2h_bytes += delta["transfers"].d2h_bytes
+        self._scrub_window()
+        self._repair_window()
+        self._writeback_window()
+        return requests
+
+    def _run_windows(self, requests: list[Request]) -> None:
+        """The foreground put/get/delete windows of one flush."""
         windows = self._windows(requests)
         # pipelined put ingest: PutWindowState for put windows whose
         # chunk pass was issued ahead of their execution slot.  Beginning
@@ -612,19 +680,6 @@ class BatchScheduler:
                 for r in window:
                     if r.status == "queued":
                         r.status, r.error = "failed", exc
-        delta = LAUNCHES.delta(before)
-        self.stats.n_flushes += 1
-        self.stats.n_requests += len(requests)
-        self.stats.n_failed += sum(1 for r in requests if not r.ok)
-        self.stats.gf_launches += delta.gf
-        self.stats.sha1_launches += delta.sha1
-        self.stats.gear_launches += delta.gear
-        self.stats.fused_launches += delta.fused
-        self.stats.flush_seconds += time.perf_counter() - t0
-        self._scrub_window()
-        self._repair_window()
-        self._writeback_window()
-        return requests
 
     def _scrub_window(self) -> bool:
         """Timer lane: run a proactive scrub sweep when one is due.
@@ -653,22 +708,22 @@ class BatchScheduler:
         ``repair_chunks_per_flush`` chunks -- one bounded recode batch
         interleaved between user flushes, never a storm-sized stall.
         """
-        from repro.kernels.launches import LAUNCHES
+        from repro.kernels.launches import LAUNCHES, span
 
         manager = getattr(self.store, "repair", None)
         if not self.repair_chunks_per_flush or manager is None \
                 or not manager.pending:
             return
         before = LAUNCHES.snapshot()
-        t0 = time.perf_counter()
-        report = manager.drain(max_chunks=self.repair_chunks_per_flush)
+        with span("sears.repair") as timed:
+            report = manager.drain(max_chunks=self.repair_chunks_per_flush)
         self.stats.n_repair_windows += 1
         self.stats.repair_chunks += report.n_chunks
         self.stats.repair_pieces_rebuilt += report.pieces_rebuilt
         self.stats.repair_pieces_replaced += report.pieces_replaced
         self.stats.repair_deferred += report.deferred
         self.stats.repair_gf_launches += LAUNCHES.delta(before).gf
-        self.stats.repair_seconds += time.perf_counter() - t0
+        self.stats.repair_seconds += timed.seconds
 
     def _writeback_window(self) -> None:
         """Background lane: drain the block cache's upload queue.
@@ -681,16 +736,18 @@ class BatchScheduler:
         where the deferred encode+store cost is actually paid, outside
         any request's latency.
         """
+        from repro.kernels.launches import span
+
         cache = getattr(self.store, "cache", None)
         if cache is None or not cache.dirty_count:
             return
-        t0 = time.perf_counter()
-        n = self.store.drain_writeback(
-            max_bytes=self.writeback_bytes_per_flush)
+        with span("sears.writeback") as timed:
+            n = self.store.drain_writeback(
+                max_bytes=self.writeback_bytes_per_flush)
         if n:
             self.stats.n_writeback_windows += 1
             self.stats.writeback_chunks += n
-        self.stats.writeback_seconds += time.perf_counter() - t0
+        self.stats.writeback_seconds += timed.seconds
 
     @staticmethod
     def _windows(requests: list[Request]) -> list[list[Request]]:

@@ -86,6 +86,7 @@ from repro.core.repair import RepairManager, RepairReport
 from repro.core.sanitizer import Sanitizer, SanitizerError  # noqa: F401
 from repro.core.shard import (ShardedBindingSlice, ShardedChunkIndex,
                               ShardedSwitchTable, ShardMap)
+from repro.kernels.launches import span
 
 
 @dataclasses.dataclass
@@ -527,24 +528,25 @@ class SEARSStore:
         window's gear budget — one launch per distinct chunker — is
         recorded up front, before the launch it covers is issued.
         """
-        san = self._sanitizer
-        if san is None:
-            return self._put_window_begin_impl(requests)
-        # per-shard launch model: one gear launch per distinct chunker
-        # per shard sub-window (each group chunks in its own pass)
-        gear = 0
-        for _sid, idxs in self._window_groups(requests):
-            chunkers = set()
-            for i in idxs:
-                try:
-                    chunkers.add(
-                        self._class(requests[i].storage_class).chunker)
-                except KeyError:
-                    pass  # the impl fails this request; it chunks nothing
-            gear += len(chunkers)
-        san.add_budget(gear=gear)
-        return san.guard_begin("_put_window_begin",
-                               self._put_window_begin_impl, requests)
+        with span("sears.put.chunk"):
+            san = self._sanitizer
+            if san is None:
+                return self._put_window_begin_impl(requests)
+            # per-shard launch model: one gear launch per distinct chunker
+            # per shard sub-window (each group chunks in its own pass)
+            gear = 0
+            for _sid, idxs in self._window_groups(requests):
+                chunkers = set()
+                for i in idxs:
+                    try:
+                        chunkers.add(
+                            self._class(requests[i].storage_class).chunker)
+                    except KeyError:
+                        pass  # the impl fails this request; it chunks nothing
+                gear += len(chunkers)
+            san.add_budget(gear=gear)
+            return san.guard_begin("_put_window_begin",
+                                   self._put_window_begin_impl, requests)
 
     def _put_window_begin_impl(self, requests) -> "PutWindowState":
         validated: list[list[tuple[str, bytes, np.ndarray]]] = []
@@ -600,10 +602,11 @@ class SEARSStore:
         try:
             if state.error is not None:
                 raise state.error
-            spans_by_group = {
-                sid: self.engine.chunk_blobs_multi_finish(
-                    state.pending[sid])
-                for sid, _ in state.groups}
+            with span("sears.put.chunk"):
+                spans_by_group = {
+                    sid: self.engine.chunk_blobs_multi_finish(
+                        state.pending[sid])
+                    for sid, _ in state.groups}
         except Exception as exc:
             # shared chunk-pass failure: nothing planned or landed yet --
             # every live request in the window fails (mirrors the shared
@@ -624,13 +627,15 @@ class SEARSStore:
 
         chunked: list[list[tuple[str, bytes, list[tuple[int, int]],
                                  list[bytes]]]] = []
-        for i, (req, cls, per_file) in enumerate(
-                zip(requests, req_cls, validated)):
-            out = []
-            for (filename, data, arr), spans in zip(per_file, spans_of[i]):
-                chunks = [arr[o:o + l].tobytes() for o, l in spans]
-                out.append((filename, data, spans, chunks))
-            chunked.append(out)
+        with span("sears.put.slice"):
+            for i, (req, cls, per_file) in enumerate(
+                    zip(requests, req_cls, validated)):
+                out = []
+                for (filename, data, arr), spans in zip(per_file,
+                                                        spans_of[i]):
+                    chunks = [arr[o:o + l].tobytes() for o, l in spans]
+                    out.append((filename, data, spans, chunks))
+                chunked.append(out)
 
         # hashing, one batch per shard sub-window -- on a fused engine
         # each group's chunks are hashed AND speculatively RS-encoded in
@@ -651,35 +656,36 @@ class SEARSStore:
             precomputed = {}
         ids_of: dict[int, list[bytes]] = {}  # request index -> flat ids
         try:
-            for sid, idxs in state.groups:
-                g_chunks: list[bytes] = []
-                g_codes: list = []
-                for i in idxs:
-                    for _, _, _, chunks in chunked[i]:
-                        g_chunks.extend(chunks)
-                        g_codes.extend([req_cls[i].code] * len(chunks))
-                if self._sanitizer is not None:
-                    # hash + encode budget per shard sub-window, from the
-                    # pre-dedup chunk list (dedup only shrinks the real
-                    # launch count below the model); a write-back commit
-                    # hashes only -- its GF budget accrues at drain time
-                    self._sanitizer.add_put_budget(
-                        g_codes, g_chunks, self.engine,
-                        staged_hash_only=self._write_back)
-                if fused:
-                    g_ids, g_pieces = self.engine.hash_encode_blobs_multi(
-                        list(zip(g_codes, g_chunks)))
-                    precomputed.update(
-                        {(code.n, code.k, cid): pieces
-                         for code, cid, pieces in zip(g_codes, g_ids,
-                                                      g_pieces)})
-                else:
-                    g_ids = self.engine.hash_chunks(g_chunks)
-                pos = 0
-                for i in idxs:
-                    n = sum(len(chunks) for _, _, _, chunks in chunked[i])
-                    ids_of[i] = g_ids[pos:pos + n]
-                    pos += n
+            with span("sears.put.hash"):
+                for sid, idxs in state.groups:
+                    g_chunks: list[bytes] = []
+                    g_codes: list = []
+                    for i in idxs:
+                        for _, _, _, chunks in chunked[i]:
+                            g_chunks.extend(chunks)
+                            g_codes.extend([req_cls[i].code] * len(chunks))
+                    if self._sanitizer is not None:
+                        # hash + encode budget per shard sub-window, from the
+                        # pre-dedup chunk list (dedup only shrinks the real
+                        # launch count below the model); a write-back commit
+                        # hashes only -- its GF budget accrues at drain time
+                        self._sanitizer.add_put_budget(
+                            g_codes, g_chunks, self.engine,
+                            staged_hash_only=self._write_back)
+                    if fused:
+                        g_ids, g_pieces = self.engine.hash_encode_blobs_multi(
+                            list(zip(g_codes, g_chunks)))
+                        precomputed.update(
+                            {(code.n, code.k, cid): pieces
+                             for code, cid, pieces in zip(g_codes, g_ids,
+                                                          g_pieces)})
+                    else:
+                        g_ids = self.engine.hash_chunks(g_chunks)
+                    pos = 0
+                    for i in idxs:
+                        n = sum(len(chunks) for _, _, _, chunks in chunked[i])
+                        ids_of[i] = g_ids[pos:pos + n]
+                        pos += n
         except Exception as exc:
             # shared hash batch failure: same blast radius as the chunk
             # pass -- nothing planned yet, fail the whole window
@@ -693,30 +699,31 @@ class SEARSStore:
         # like sequential calls -- across shard groups too); a failure
         # unwinds only its own request
         plans_by_req: dict[int, list[UploadPlan]] = {}
-        for i, (req, cls, per_file) in enumerate(
-                zip(requests, req_cls, chunked)):
-            if req.error is not None:
-                continue
-            plans: list[UploadPlan] = []
-            ids_flat = ids_of[i]
-            req_pos = 0
-            try:
-                for filename, data, spans, chunks in per_file:
-                    ids = ids_flat[req_pos:req_pos + len(spans)]
-                    req_pos += len(spans)
-                    plans.append(self._plan_put(
-                        req.user, filename, data, spans, ids, chunks,
-                        req.timestamp, cls, request_id=req.request_id))
-                plans_by_req[req.request_id] = plans
-            except Exception as exc:
-                # completed plans still hold their reservations (the
-                # partial plan cleaned itself up before propagating)
-                for p in plans:
-                    for t in p.encode_tasks:
-                        self.clusters[t.cluster_id].release_reservation(
-                            self.clusters[t.cluster_id].n * t.piece_len)
-                self._rollback_files(req.user, plans)
-                req.status, req.error = "failed", exc
+        with span("sears.put.plan"):
+            for i, (req, cls, per_file) in enumerate(
+                    zip(requests, req_cls, chunked)):
+                if req.error is not None:
+                    continue
+                plans: list[UploadPlan] = []
+                ids_flat = ids_of[i]
+                req_pos = 0
+                try:
+                    for filename, data, spans, chunks in per_file:
+                        ids = ids_flat[req_pos:req_pos + len(spans)]
+                        req_pos += len(spans)
+                        plans.append(self._plan_put(
+                            req.user, filename, data, spans, ids, chunks,
+                            req.timestamp, cls, request_id=req.request_id))
+                    plans_by_req[req.request_id] = plans
+                except Exception as exc:
+                    # completed plans still hold their reservations (the
+                    # partial plan cleaned itself up before propagating)
+                    for p in plans:
+                        for t in p.encode_tasks:
+                            self.clusters[t.cluster_id].release_reservation(
+                                self.clusters[t.cluster_id].n * t.piece_len)
+                    self._rollback_files(req.user, plans)
+                    req.status, req.error = "failed", exc
 
         # data plane: per shard sub-window, one shared encode batch per
         # code + bulk piece writes.  Encoding is content-deterministic,
@@ -900,54 +907,59 @@ class SEARSStore:
         releasing all reservations).
         """
         pre = precomputed or {}
-        tasks = [t for p in plans for t in p.encode_tasks]
-        # a later file in the batch may have overwritten/deleted an earlier
-        # one; drop tasks whose chunk copy is no longer indexed
-        live = [t for t in tasks
-                if self.index.get(t.chunk_id, t.cluster_id) is not None]
-        dead = [t for t in tasks
-                if self.index.get(t.chunk_id, t.cluster_id) is None]
-        for t in dead:
-            self.clusters[t.cluster_id].release_reservation(
-                self.clusters[t.cluster_id].n * t.piece_len)
-        reserved: dict[int, int] = {}
-        for t in live:
-            reserved[t.cluster_id] = (
-                reserved.get(t.cluster_id, 0)
-                + self.clusters[t.cluster_id].n * t.piece_len)
-        ready: dict[int, list[bytes]] = {}
-        to_encode = []
-        for i, t in enumerate(live):
-            code = self.clusters[t.cluster_id].code
-            hit = pre.get((code.n, code.k, t.chunk_id))
-            if hit is not None:
-                ready[i] = hit
-            else:
-                to_encode.append((i, t))
+        # landing the pieces, around the encode: which new chunk copies
+        # are still live, their reservations, then the per-cluster writes
+        with span("sears.put.write"):
+            tasks = [t for p in plans for t in p.encode_tasks]
+            # a later file in the batch may have overwritten/deleted an earlier
+            # one; drop tasks whose chunk copy is no longer indexed
+            live = [t for t in tasks
+                    if self.index.get(t.chunk_id, t.cluster_id) is not None]
+            dead = [t for t in tasks
+                    if self.index.get(t.chunk_id, t.cluster_id) is None]
+            for t in dead:
+                self.clusters[t.cluster_id].release_reservation(
+                    self.clusters[t.cluster_id].n * t.piece_len)
+            reserved: dict[int, int] = {}
+            for t in live:
+                reserved[t.cluster_id] = (
+                    reserved.get(t.cluster_id, 0)
+                    + self.clusters[t.cluster_id].n * t.piece_len)
+            ready: dict[int, list[bytes]] = {}
+            to_encode = []
+            for i, t in enumerate(live):
+                code = self.clusters[t.cluster_id].code
+                hit = pre.get((code.n, code.k, t.chunk_id))
+                if hit is not None:
+                    ready[i] = hit
+                else:
+                    to_encode.append((i, t))
         try:
-            encoded = self.engine.encode_blobs_multi(
-                [(self.clusters[t.cluster_id].code, t.data)
-                 for _, t in to_encode])  # coding nodes
+            with span("sears.put.encode"):
+                encoded = self.engine.encode_blobs_multi(
+                    [(self.clusters[t.cluster_id].code, t.data)
+                     for _, t in to_encode])  # coding nodes
         except Exception:
             for cluster_id, nbytes in reserved.items():
                 self.clusters[cluster_id].release_reservation(nbytes)
             raise
-        for (i, _), pieces in zip(to_encode, encoded):
-            ready[i] = pieces
-        by_cluster: dict[int, list[tuple[bytes, list[bytes]]]] = {}
-        for i, t in enumerate(live):
-            by_cluster.setdefault(t.cluster_id, []).append(
-                (t.chunk_id, ready[i]))
         failed: set[tuple[bytes, int]] = set()
         error: Exception | None = None
-        for cluster_id, items in by_cluster.items():
-            try:
-                self.clusters[cluster_id].store_chunks(
-                    items, min_pieces=self.clusters[cluster_id].k,
-                    reserved=reserved.pop(cluster_id, 0))
-            except Exception as exc:  # store_chunks released the bytes
-                failed.update((cid, cluster_id) for cid, _ in items)
-                error = error or exc
+        with span("sears.put.write"):
+            for (i, _), pieces in zip(to_encode, encoded):
+                ready[i] = pieces
+            by_cluster: dict[int, list[tuple[bytes, list[bytes]]]] = {}
+            for i, t in enumerate(live):
+                by_cluster.setdefault(t.cluster_id, []).append(
+                    (t.chunk_id, ready[i]))
+            for cluster_id, items in by_cluster.items():
+                try:
+                    self.clusters[cluster_id].store_chunks(
+                        items, min_pieces=self.clusters[cluster_id].k,
+                        reserved=reserved.pop(cluster_id, 0))
+                except Exception as exc:  # store_chunks released the bytes
+                    failed.update((cid, cluster_id) for cid, _ in items)
+                    error = error or exc
         return failed, error
 
     # ------------------------------------------------------- write-back ---
@@ -1218,15 +1230,16 @@ class SEARSStore:
         Results/errors are recorded on the request objects.
         """
         plans_by_req: dict[int, list[RetrievalPlan]] = {}
-        for req in requests:
-            try:
-                plans_by_req[req.request_id] = [
-                    self._plan_get(req.user, fn, req.local_chunk_ids,
-                                   request_id=req.request_id,
-                                   storage_class=req.storage_class)
-                    for fn in req.filenames]
-            except Exception as exc:
-                req.status, req.error = "failed", exc
+        with span("sears.get.plan"):
+            for req in requests:
+                try:
+                    plans_by_req[req.request_id] = [
+                        self._plan_get(req.user, fn, req.local_chunk_ids,
+                                       request_id=req.request_id,
+                                       storage_class=req.storage_class)
+                        for fn in req.filenames]
+                except Exception as exc:
+                    req.status, req.error = "failed", exc
 
         # data plane: per shard sub-window, bulk piece reads per cluster
         # across the group's requests; reads have no store side effects,
@@ -1244,44 +1257,47 @@ class SEARSStore:
         try:
             all_tasks = [t for r in live for p in plans_by_req[r.request_id]
                          for t in p.fetch_tasks]
-            for sid, greqs in groups:
-                by_cluster: dict[int, list[FetchTask]] = {}
-                for r in greqs:
-                    for p in plans_by_req[r.request_id]:
-                        for t in p.fetch_tasks:
-                            by_cluster.setdefault(t.cluster_id,
-                                                  []).append(t)
-                for cluster_id, tasks in by_cluster.items():
-                    got = self._read_cluster_pieces(
-                        cluster_id, [t.chunk_id for t in tasks])
-                    for t in tasks:
-                        t.pieces = got[t.chunk_id]
+            with span("sears.get.read"):
+                for sid, greqs in groups:
+                    by_cluster: dict[int, list[FetchTask]] = {}
+                    for r in greqs:
+                        for p in plans_by_req[r.request_id]:
+                            for t in p.fetch_tasks:
+                                by_cluster.setdefault(t.cluster_id,
+                                                      []).append(t)
+                    for cluster_id, tasks in by_cluster.items():
+                        got = self._read_cluster_pieces(
+                            cluster_id, [t.chunk_id for t in tasks])
+                        for t in tasks:
+                            t.pieces = got[t.chunk_id]
         except Exception as exc:
             for req in live:
                 req.status, req.error = "failed", exc
             return
 
-        # read-repair: a non-systematic piece set means a node in the
-        # systematic prefix was dead or had lost its piece -- hint the
-        # repair queue so hot degraded chunks heal without waiting for a
-        # full scan (the hint censuses the chunk and drops false alarms,
-        # e.g. a holder that is merely down with its piece intact)
-        for t in all_tasks:
-            systematic = set(range(self.clusters[t.cluster_id].k))
-            if t.pieces is not None and set(t.pieces) != systematic:
-                self.repair.hint(t.chunk_id, t.cluster_id)
+        with span("sears.get.hint"):
+            # read-repair: a non-systematic piece set means a node in the
+            # systematic prefix was dead or had lost its piece -- hint the
+            # repair queue so hot degraded chunks heal without waiting for a
+            # full scan (the hint censuses the chunk and drops false alarms,
+            # e.g. a holder that is merely down with its piece intact)
+            for t in all_tasks:
+                systematic = set(range(self.clusters[t.cluster_id].k))
+                if t.pieces is not None and set(t.pieces) != systematic:
+                    self.repair.hint(t.chunk_id, t.cluster_id)
 
-        # demux data loss to its request before the shared decode so one
-        # unrecoverable chunk cannot poison the whole window
-        for req in live:
-            for p in plans_by_req[req.request_id]:
-                for t in p.fetch_tasks:
-                    want = self.clusters[t.cluster_id].k
-                    if len(t.pieces) < want and req.error is None:
-                        req.status = "failed"
-                        req.error = ValueError(
-                            f"need >= k={want} pieces to decode, got "
-                            f"{len(t.pieces)} (chunk {t.chunk_id.hex()})")
+            # demux data loss to its request before the shared decode so one
+            # unrecoverable chunk cannot poison the whole window
+            for req in live:
+                for p in plans_by_req[req.request_id]:
+                    for t in p.fetch_tasks:
+                        want = self.clusters[t.cluster_id].k
+                        if len(t.pieces) < want and req.error is None:
+                            req.status = "failed"
+                            req.error = ValueError(
+                                f"need >= k={want} pieces to decode, got "
+                                f"{len(t.pieces)} (chunk {t.chunk_id.hex()})")
+
         live = [r for r in live if r.error is None]
 
         # shared decode per shard sub-window, deduplicated within the
@@ -1293,51 +1309,53 @@ class SEARSStore:
         # to identical bytes in each.
         blob_by_key: dict[tuple[bytes, int], bytes] = {}
         try:
-            for sid, greqs in groups:
-                uniq: dict[tuple[bytes, int], FetchTask] = {}
-                for req in greqs:
-                    if req.error is not None:
-                        continue
-                    for p in plans_by_req[req.request_id]:
-                        for t in p.fetch_tasks:
-                            uniq.setdefault((t.chunk_id, t.cluster_id), t)
-                jobs = [(self.clusters[t.cluster_id].code, t.pieces,
-                         t.length) for t in uniq.values()]
-                if self._sanitizer is not None:
-                    # same decode model as _get_window_begin, per shard
-                    # sub-window: one GF launch per unique chunk is the
-                    # ceiling, bucketing stays below
-                    self._sanitizer.add_budget(gf=len(jobs))
-                    blobs = self._sanitizer.track(
-                        self.engine.decode_blobs_multi, jobs)
-                else:
-                    blobs = self.engine.decode_blobs_multi(jobs)
-                blob_by_key.update(zip(uniq, blobs))
+            with span("sears.get.decode"):
+                for sid, greqs in groups:
+                    uniq: dict[tuple[bytes, int], FetchTask] = {}
+                    for req in greqs:
+                        if req.error is not None:
+                            continue
+                        for p in plans_by_req[req.request_id]:
+                            for t in p.fetch_tasks:
+                                uniq.setdefault((t.chunk_id, t.cluster_id), t)
+                    jobs = [(self.clusters[t.cluster_id].code, t.pieces,
+                             t.length) for t in uniq.values()]
+                    if self._sanitizer is not None:
+                        # same decode model as _get_window_begin, per shard
+                        # sub-window: one GF launch per unique chunk is the
+                        # ceiling, bucketing stays below
+                        self._sanitizer.add_budget(gf=len(jobs))
+                        blobs = self._sanitizer.track(
+                            self.engine.decode_blobs_multi, jobs)
+                    else:
+                        blobs = self.engine.decode_blobs_multi(jobs)
+                    blob_by_key.update(zip(uniq, blobs))
         except Exception as exc:
             for req in live:
                 req.status, req.error = "failed", exc
             return
 
-        # read-fill: every decoded chunk becomes a clean cache entry (in
-        # deterministic plan order), so the next window's repeats hit
-        if self.cache is not None:
-            for (cid, cl), blob in blob_by_key.items():
-                self.cache.fill(cid, cl, blob)
+        with span("sears.get.assemble"):
+            # read-fill: every decoded chunk becomes a clean cache entry (in
+            # deterministic plan order), so the next window's repeats hit
+            if self.cache is not None:
+                for (cid, cl), blob in blob_by_key.items():
+                    self.cache.fill(cid, cl, blob)
 
-        # assemble + stats per file, fanned back out per request (a bad
-        # per-request rho_fn fails only its own request)
-        for req in live:
-            try:
-                out = [self._assemble(
-                    plan,
-                    {t.chunk_id: blob_by_key[(t.chunk_id, t.cluster_id)]
-                     for t in plan.fetch_tasks},
-                    req.rho_fn) for plan in plans_by_req[req.request_id]]
-            except Exception as exc:
-                req.status, req.error = "failed", exc
-                continue
-            req.result = out
-            req.status = "done"
+            # assemble + stats per file, fanned back out per request (a bad
+            # per-request rho_fn fails only its own request)
+            for req in live:
+                try:
+                    out = [self._assemble(
+                        plan,
+                        {t.chunk_id: blob_by_key[(t.chunk_id, t.cluster_id)]
+                         for t in plan.fetch_tasks},
+                        req.rho_fn) for plan in plans_by_req[req.request_id]]
+                except Exception as exc:
+                    req.status, req.error = "failed", exc
+                    continue
+                req.result = out
+                req.status = "done"
 
         if self._sanitizer is not None:
             self._sanitizer.check_launches("get window")

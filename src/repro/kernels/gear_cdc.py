@@ -171,6 +171,9 @@ def _gear_fire_padded(data: jnp.ndarray, gear: jnp.ndarray,
         out_specs=pl.BlockSpec((ROWS, LANES), lambda p: (p, 0)),
         out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.bool_),
         interpret=interpret,
+        # the kernel's instruction name in a device trace, kept if this
+        # wrapper is renamed (the benchmark's rooflines match it)
+        name="_gear_fire_padded",
     )(rows, rows, gear, mask).reshape(n)
 
 
@@ -205,6 +208,9 @@ def _gear_hash_padded(data: jnp.ndarray, gear: jnp.ndarray, *,
         out_specs=pl.BlockSpec((ROWS, LANES), lambda p: (p, 0)),
         out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.uint32),
         interpret=interpret,
+        # the kernel's instruction name in a device trace, kept if this
+        # wrapper is renamed (the benchmark's rooflines match it)
+        name="_gear_hash_padded",
     )(rows, rows, gear).reshape(n)
 
 

@@ -57,7 +57,7 @@ def use_compile_cache() -> str:
 # re-exported for existing callers; the counters themselves live in a
 # dependency-free module so readers need not import jax
 from repro.kernels.launches import (LAUNCHES, TRACES,  # noqa: E402,F401
-                                    LaunchCounter)
+                                    LaunchCounter, shipped, span, to_host)
 
 
 # ---------------------------------------------------------------- GF matmul
@@ -84,11 +84,13 @@ def rs_apply(M: np.ndarray, data, impl: str = "kernel") -> jnp.ndarray:
     RS decode: M = decode_matrix(n, k, received_idx) -> (B, k, L) data.
     """
     LAUNCHES.gf += 1
-    if impl == "ref":
-        M = np.ascontiguousarray(np.asarray(M, dtype=np.uint8))
-        Mdev = _device_matrix(M.tobytes(), *M.shape)
-        return _gf_ref_jit(Mdev, jnp.asarray(data, jnp.uint8))
-    return gf_matmul.gf_matmul(M, data, interpret=not _on_tpu())
+    with span("sears.engine.dispatch"):
+        shipped(data)
+        if impl == "ref":
+            M = np.ascontiguousarray(np.asarray(M, dtype=np.uint8))
+            Mdev = _device_matrix(M.tobytes(), *M.shape)
+            return _gf_ref_jit(Mdev, jnp.asarray(data, jnp.uint8))
+        return gf_matmul.gf_matmul(M, data, interpret=not _on_tpu())
 
 
 def rs_encode(code, data, impl: str = "kernel") -> jnp.ndarray:
@@ -188,9 +190,13 @@ def gear_hash(data, impl: str = "kernel") -> jnp.ndarray:
     if n == 0:
         return jnp.zeros((0,), jnp.uint32)
     LAUNCHES.gear += 1
-    if impl == "ref":
-        return _gear_ref_padded(gear_cdc.pad_to_bucket(data))[:n]
-    return gear_cdc.gear_hash(data, interpret=not _on_tpu())
+    with span("sears.engine.dispatch"):
+        if impl == "ref":
+            padded = gear_cdc.pad_to_bucket(data)
+            shipped(padded)
+            return _gear_ref_padded(padded)[:n]
+        shipped(data)
+        return gear_cdc.gear_hash(data, interpret=not _on_tpu())
 
 
 def gear_hash_stream(data, impl: str = "kernel") -> np.ndarray:
@@ -198,7 +204,7 @@ def gear_hash_stream(data, impl: str = "kernel") -> np.ndarray:
     data = np.asarray(data, np.uint8)
     if data.shape[0] == 0:
         return np.zeros((0,), np.uint32)
-    return np.asarray(gear_hash(data, impl=impl))
+    return to_host(gear_hash(data, impl=impl))
 
 
 @jax.jit
@@ -224,19 +230,24 @@ def gear_fire_issue(data, mask, impl: str = "kernel"):
     if data.shape[0] == 0:
         return None
     LAUNCHES.gear += 1
-    if impl == "ref":
-        n = data.shape[0]
-        return _gear_fire_ref(gear_cdc.pad_to_bucket(data),
-                              jnp.uint32(np.uint32(mask)))[:n]
-    return gear_cdc.gear_fire(data, np.uint32(mask),
-                              interpret=not _on_tpu())
+    mask = np.uint32(mask)
+    with span("sears.engine.dispatch"):
+        if impl == "ref":
+            n = data.shape[0]
+            padded = gear_cdc.pad_to_bucket(data)
+            shipped(padded, mask)
+            return _gear_fire_ref(padded, jnp.uint32(mask))[:n]
+        shipped(data, mask)
+        return gear_cdc.gear_fire(data, mask, interpret=not _on_tpu())
 
 
 def gear_fire_resolve(fire) -> np.ndarray:
     """Materialize an issued fire bitmap -> sorted candidate positions."""
     if fire is None:
         return np.zeros(0, np.int64)
-    return np.flatnonzero(np.asarray(fire)).astype(np.int64)
+    fire = to_host(fire)
+    with span("sears.engine.unpack"):
+        return np.flatnonzero(fire).astype(np.int64)
 
 
 def gear_candidate_positions(data, mask, impl: str = "kernel") -> np.ndarray:
@@ -324,17 +335,22 @@ def sha1_digests(chunks: list[bytes], impl: str = "kernel") -> list[bytes]:
     """Batched SHA-1 of byte chunks -> 20-byte digests (device hot path)."""
     if not chunks:
         return []
-    blocks, counts = hashing.sha1_pad_batch(chunks)
-    words = sha1_digest_words(blocks, counts, impl=impl)
-    return hashing.digest_words_to_bytes(np.asarray(words))
+    with span("sears.engine.pack"):
+        blocks, counts = hashing.sha1_pad_batch(chunks)
+    words = to_host(sha1_digest_words(blocks, counts, impl=impl))
+    with span("sears.engine.unpack"):
+        return hashing.digest_words_to_bytes(words)
 
 
 def sha1_digest_words(blocks, counts, impl: str = "kernel") -> jnp.ndarray:
     LAUNCHES.sha1 += 1
-    if impl == "ref":
-        return _sha1_ref_loop(jnp.asarray(blocks, jnp.uint32),
-                              jnp.asarray(counts, jnp.int32).reshape(-1))
-    return sha1.sha1_digest_words(blocks, counts, interpret=not _on_tpu())
+    with span("sears.engine.dispatch"):
+        shipped(blocks, counts)
+        if impl == "ref":
+            return _sha1_ref_loop(jnp.asarray(blocks, jnp.uint32),
+                                  jnp.asarray(counts, jnp.int32).reshape(-1))
+        return sha1.sha1_digest_words(blocks, counts,
+                                      interpret=not _on_tpu())
 
 
 # ----------------------------------------------------------- fused ingest --
@@ -379,38 +395,45 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
     from repro.kernels.gf_matmul import TILE_L
     if not blobs:
         return [], []
-    G = np.ascontiguousarray(np.asarray(
-        rs_code.generator_matrix(code.n, code.k), dtype=np.uint8))
-    piece_lens = [code.piece_len(len(b)) for b in blobs]
+    with span("sears.engine.pack"):
+        G = np.ascontiguousarray(np.asarray(
+            rs_code.generator_matrix(code.n, code.k), dtype=np.uint8))
+        piece_lens = [code.piece_len(len(b)) for b in blobs]
+        buckets = rs_code.bucket_by_piece_len(piece_lens, TILE_L)
     ids: list[bytes | None] = [None] * len(blobs)
     pieces: list[list[bytes] | None] = [None] * len(blobs)
-    for Lp, idxs in rs_code.bucket_by_piece_len(piece_lens, TILE_L).items():
-        Bp = _pow2(len(idxs))
-        data = np.zeros((Bp, code.k, Lp), dtype=np.uint8)
-        group: list[bytes] = []
-        for row, i in enumerate(idxs):
-            data[row] = rs_code.pack_blob(blobs[i], code.k,
-                                          piece_lens[i], Lp)
-            group.append(blobs[i])
-        group += [b""] * (Bp - len(idxs))
-        blocks, counts = hashing.sha1_pad_batch(group, max_len=code.k * Lp)
+    for Lp, idxs in buckets.items():
+        with span("sears.engine.pack"):
+            Bp = _pow2(len(idxs))
+            data = np.zeros((Bp, code.k, Lp), dtype=np.uint8)
+            group: list[bytes] = []
+            for row, i in enumerate(idxs):
+                data[row] = rs_code.pack_blob(blobs[i], code.k,
+                                              piece_lens[i], Lp)
+                group.append(blobs[i])
+            group += [b""] * (Bp - len(idxs))
+            blocks, counts = hashing.sha1_pad_batch(group,
+                                                    max_len=code.k * Lp)
         LAUNCHES.fused += 1
-        if impl == "ref":
-            Mdev = _device_matrix(G.tobytes(), *G.shape)
-            words, enc = _fused_ingest_ref(
-                Mdev, jnp.asarray(blocks, jnp.uint32),
-                jnp.asarray(counts, jnp.int32), jnp.asarray(data))
-        else:
-            gbits = gf_matmul._gbits_cached(G.tobytes(), *G.shape)
-            words, enc = _fused_ingest_pallas(
-                gbits, jnp.asarray(blocks, jnp.uint32),
-                jnp.asarray(counts, jnp.int32), jnp.asarray(data),
-                interpret=not _on_tpu())
-        digests = hashing.digest_words_to_bytes(
-            np.asarray(words)[:len(idxs)])
-        enc = np.asarray(enc)
-        for row, i in enumerate(idxs):
-            L = piece_lens[i]
-            ids[i] = digests[row]
-            pieces[i] = [enc[row, j, :L].tobytes() for j in range(code.n)]
+        with span("sears.engine.dispatch"):
+            shipped(blocks, counts, data)
+            if impl == "ref":
+                Mdev = _device_matrix(G.tobytes(), *G.shape)
+                words, enc = _fused_ingest_ref(
+                    Mdev, jnp.asarray(blocks, jnp.uint32),
+                    jnp.asarray(counts, jnp.int32), jnp.asarray(data))
+            else:
+                gbits = gf_matmul._gbits_cached(G.tobytes(), *G.shape)
+                words, enc = _fused_ingest_pallas(
+                    gbits, jnp.asarray(blocks, jnp.uint32),
+                    jnp.asarray(counts, jnp.int32), jnp.asarray(data),
+                    interpret=not _on_tpu())
+        words, enc = to_host(words), to_host(enc)
+        with span("sears.engine.unpack"):
+            digests = hashing.digest_words_to_bytes(words[:len(idxs)])
+            for row, i in enumerate(idxs):
+                L = piece_lens[i]
+                ids[i] = digests[row]
+                pieces[i] = [enc[row, j, :L].tobytes()
+                             for j in range(code.n)]
     return ids, pieces  # type: ignore[return-value]

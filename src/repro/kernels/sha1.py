@@ -86,6 +86,9 @@ def _sha1_padded(blocks: jnp.ndarray, counts: jnp.ndarray, *,
         out_specs=pl.BlockSpec((5, tile), lambda b: (0, b)),
         out_shape=jax.ShapeDtypeStruct((5, B), jnp.uint32),
         interpret=interpret,
+        # the kernel's instruction name in a device trace, kept if this
+        # wrapper is renamed (the benchmark's rooflines match it)
+        name="_sha1_padded",
     )(blocks.transpose(1, 2, 0), counts.reshape(1, B)).T
 
 

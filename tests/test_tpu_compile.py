@@ -127,3 +127,23 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for s, d in shapes]
     compiled = fn.lower(*args, interpret=False, **static).compile()
     assert "tpu_custom_call" in compiled.as_text()  # a Mosaic kernel
+
+
+# the instruction each kernel shows in a device trace, whatever the name
+# of the jitted wrapper around it (the benchmark's rooflines match these)
+TRACE_NAMES = {"gear_fire_64MiB": ["_gear_fire_padded"],
+               "gf_encode_10_5": ["_gf_matmul_padded"],
+               "sha1_8KiB_cap": ["_sha1_padded"],
+               "fused_realtime": ["_sha1_padded", "_gf_matmul_padded"]}
+
+
+@pytest.mark.parametrize("name", sorted(TRACE_NAMES))
+def test_kernel_instruction_is_named_by_its_pallas_call(name, one_chip):
+    fn, shapes, static = _case(name)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = fn.lower(*args, interpret=False, **static).compile().as_text()
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sorted(calls) == sorted(TRACE_NAMES[name])
