@@ -9,7 +9,8 @@ the first to arrive, reconstruction is a memcpy.
 Encode/decode of batches is delegated to ``repro.kernels.ops`` (bit-sliced
 Pallas kernel with pure-jnp fallback); this module provides the host-side
 numpy path used by the storage simulator plus the matrix machinery shared
-by both.
+by both.  Batched encoding applies only the parity block ``P``: the k data
+pieces are cut from the blob's own bytes (``data_pieces``).
 """
 
 from __future__ import annotations
@@ -36,6 +37,16 @@ def generator_matrix(n: int, k: int) -> np.ndarray:
     denom = x[:, None] ^ y[None, :]  # GF addition is XOR
     P = gf256.gf_inv(denom)
     return np.concatenate([ident, P], axis=0)
+
+
+@functools.lru_cache(maxsize=None)
+def parity_matrix(n: int, k: int) -> np.ndarray:
+    """The (n-k, k) parity block ``P`` of ``generator_matrix``, int32.
+
+    Rows k.. of G: all that an encode computes, since rows :k are the
+    identity and their pieces are the blob's own bytes.
+    """
+    return np.ascontiguousarray(generator_matrix(n, k)[k:])
 
 
 @functools.lru_cache(maxsize=None)
@@ -89,6 +100,17 @@ def pack_blob(blob: bytes, k: int, piece_len: int,
     return out
 
 
+def data_pieces(blob: bytes, k: int, piece_len: int) -> list[bytes]:
+    """The k systematic pieces of a blob, cut from its own bytes.
+
+    Piece j is ``blob[j*L:(j+1)*L]`` zero-padded to L: rows :k of
+    ``RSCode.encode_bytes``, which the identity rows of G would copy.
+    """
+    L = piece_len
+    padded = bytes(blob).ljust(k * L, b"\0")
+    return [padded[j * L:(j + 1) * L] for j in range(k)]
+
+
 def pack_pieces(pieces: dict[int, bytes], indices: tuple[int, ...],
                 piece_len: int, padded_len: int | None = None) -> np.ndarray:
     """Stack received pieces (in ``indices`` order) as (k, Lp) uint8."""
@@ -126,26 +148,39 @@ def batch_encode_blobs(code: "RSCode", blobs: list[bytes], apply_fn,
     """Encode blobs -> n pieces each, one ``apply_fn`` call per bucket.
 
     ``apply_fn(M, arr)`` applies a GF(256) matrix to (B, k, Lp) uint8 and
-    returns (B, r, Lp); ``pad_batch`` rounds the batch axis up (e.g. to a
-    power of two to bound compiled kernel shapes).
+    returns (B, r, Lp); it is given only the (n-k, k) parity block, so the
+    k data pieces are neither computed nor copied back (``data_pieces``).
+    ``pad_batch`` rounds the batch axis up (e.g. to a power of two to
+    bound compiled kernel shapes).
     """
     with span("sears.engine.pack"):
         piece_lens = [code.piece_len(len(b)) for b in blobs]
         buckets = bucket_by_piece_len(piece_lens, quantum)
     out: list[list[bytes] | None] = [None] * len(blobs)
-    G = generator_matrix(code.n, code.k)
+    P = parity_matrix(code.n, code.k)
     for Lp, idxs in buckets.items():
         with span("sears.engine.pack"):
             arr = np.zeros((pad_batch(len(idxs)), code.k, Lp),
                            dtype=np.uint8)
             for row, i in enumerate(idxs):
                 arr[row] = pack_blob(blobs[i], code.k, piece_lens[i], Lp)
-        enc = to_host(apply_fn(G, arr))  # (Bp, n, Lp)
+        parity = to_host(apply_fn(P, arr))  # (Bp, n-k, Lp)
         with span("sears.engine.unpack"):
-            for row, i in enumerate(idxs):
-                L = piece_lens[i]
-                out[i] = [enc[row, j, :L].tobytes() for j in range(code.n)]
+            unpack_encoded(code, blobs, piece_lens, idxs, parity, out)
     return out  # type: ignore[return-value]
+
+
+def unpack_encoded(code: "RSCode", blobs: list[bytes], piece_lens: list[int],
+                   idxs: list[int], parity: np.ndarray, out: list) -> None:
+    """Fill ``out[i]`` with blob i's n pieces for each i of a bucket.
+
+    ``parity`` is the bucket's (Bp, n-k, Lp) result, row ``r`` holding
+    ``idxs[r]``; the data pieces come from the blobs themselves.
+    """
+    for row, i in enumerate(idxs):
+        L = piece_lens[i]
+        out[i] = data_pieces(blobs[i], code.k, L) + [
+            p[:L].tobytes() for p in parity[row]]
 
 
 def batch_decode_blobs_begin(code: "RSCode",
@@ -289,7 +324,7 @@ class RSCode:
     # -- batch bytes API (numpy; bucketed by piece length) ----------------
     def encode_blobs(self, blobs: list[bytes], quantum: int = 1
                      ) -> list[list[bytes]]:
-        """Batched ``encode_bytes``: one matmul per piece-length bucket."""
+        """Batched ``encode_bytes``: one parity matmul per length bucket."""
         return batch_encode_blobs(self, blobs, _gf_matmul_batched_np,
                                   quantum=quantum)
 

@@ -113,9 +113,10 @@ def rs_decode(code, pieces, indices, impl: str = "kernel") -> jnp.ndarray:
 # serves a whole bucket; the batch axis is padded to the next power of
 # two to bound the set of compiled (B, k, L) shapes.  Zero pad columns /
 # rows are exact under GF(256) (coding is per byte column), so sliced
-# results are byte-identical to per-blob host encoding.  The bucketing
-# itself lives in ``rs_code.batch_{encode,decode}_blobs``; here we only
-# supply the kernel apply_fn and the TPU-shaped padding policy.
+# results are byte-identical to per-blob host encoding.  Encoding applies
+# only the (n-k, k) parity block (``rs_code.parity_matrix``).  The
+# bucketing itself lives in ``rs_code.batch_{encode,decode}_blobs``; here
+# we only supply the kernel apply_fn and the TPU-shaped padding policy.
 
 def _pow2(n: int) -> int:
     return 1 << max(0, n - 1).bit_length() if n > 1 else 1
@@ -388,7 +389,9 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
     costs O(length buckets) fused launches; the SHA-1 message schedule is
     capped at ``k * Lp`` bytes per bucket -- every blob of the bucket
     fits by construction (``piece_len(len) <= Lp``), so there is no
-    oversized-chunk fallback on this path.  Byte-identical to running
+    oversized-chunk fallback on this path.  Like the staged encode it
+    computes and copies back only the parity and unpacks with the same
+    ``rs_code.unpack_encoded``: byte-identical to running
     ``sha1_digests`` and ``rs_encode_blobs`` separately.
     """
     from repro.core import rs_code
@@ -396,8 +399,8 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
     if not blobs:
         return [], []
     with span("sears.engine.pack"):
-        G = np.ascontiguousarray(np.asarray(
-            rs_code.generator_matrix(code.n, code.k), dtype=np.uint8))
+        P = np.ascontiguousarray(np.asarray(
+            rs_code.parity_matrix(code.n, code.k), dtype=np.uint8))
         piece_lens = [code.piece_len(len(b)) for b in blobs]
         buckets = rs_code.bucket_by_piece_len(piece_lens, TILE_L)
     ids: list[bytes | None] = [None] * len(blobs)
@@ -418,22 +421,21 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
         with span("sears.engine.dispatch"):
             shipped(blocks, counts, data)
             if impl == "ref":
-                Mdev = _device_matrix(G.tobytes(), *G.shape)
-                words, enc = _fused_ingest_ref(
-                    Mdev, jnp.asarray(blocks, jnp.uint32),
+                Pdev = _device_matrix(P.tobytes(), *P.shape)
+                words, parity = _fused_ingest_ref(
+                    Pdev, jnp.asarray(blocks, jnp.uint32),
                     jnp.asarray(counts, jnp.int32), jnp.asarray(data))
             else:
-                gbits = gf_matmul._gbits_cached(G.tobytes(), *G.shape)
-                words, enc = _fused_ingest_pallas(
+                gbits = gf_matmul._gbits_cached(P.tobytes(), *P.shape)
+                words, parity = _fused_ingest_pallas(
                     gbits, jnp.asarray(blocks, jnp.uint32),
                     jnp.asarray(counts, jnp.int32), jnp.asarray(data),
                     interpret=not _on_tpu())
-        words, enc = to_host(words), to_host(enc)
+        words, parity = to_host(words), to_host(parity)
         with span("sears.engine.unpack"):
             digests = hashing.digest_words_to_bytes(words[:len(idxs)])
             for row, i in enumerate(idxs):
-                L = piece_lens[i]
                 ids[i] = digests[row]
-                pieces[i] = [enc[row, j, :L].tobytes()
-                             for j in range(code.n)]
+            rs_code.unpack_encoded(code, blobs, piece_lens, idxs, parity,
+                                   pieces)
     return ids, pieces  # type: ignore[return-value]

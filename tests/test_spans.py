@@ -126,19 +126,22 @@ def test_h2d_bytes_equal_the_launched_shapes(monkeypatch):
     stream = sum(len(d) for _, d in files)
     gear = [shapes for name, shapes, _ in seen if name == "_gear_fire_ref"]
     sha1 = [shapes for name, shapes, _ in seen if name == "_sha1_ref_loop"]
-    gf = [shapes for name, shapes, _ in seen if name == "_gf_ref_jit"]
+    gf = [(shapes, b) for name, shapes, b in seen
+          if name == "_gf_ref_jit"]
     assert gear == [[(gear_cdc.bucket_len(stream),), ()]]
     assert len(sha1) > 1 and gf  # several hash batches, some GF buckets
     h2d = gear_cdc.bucket_len(stream) + 4  # the stream and its mask
     for blocks, counts in sha1:
         assert blocks[1:] == (blocks[1], 16) and counts == blocks[:1]
         h2d += 4 * int(np.prod(blocks)) + 4 * counts[0]
-    for matrix, data in gf:
+    for (matrix, data), back in gf:
+        assert matrix == (CLASS.n - CLASS.k, CLASS.k)  # the parity block
         assert data[1] == CLASS.k and data[2] % 512 == 0
+        assert back == data[0] * (CLASS.n - CLASS.k) * data[2]
         h2d += int(np.prod(data))  # the coding matrix stays on the device
     assert moved.h2d_bytes == h2d
     # back: one fire flag per stream byte, 5 digest words per hash lane,
-    # n rows per GF lane
+    # n-k parity rows per GF lane
     d2h = stream + sum(b for name, _, b in seen if b is not None)
     assert moved.d2h_bytes == d2h
 

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.core import gf256
-from repro.core.rs_code import RSCode, decode_matrix, generator_matrix
+from repro.core.rs_code import RSCode, decode_matrix, parity_matrix
 from repro.kernels import gear_cdc, gf_matmul, ops, sha1
 from repro.kernels.launches import TRACES
 
@@ -80,11 +80,11 @@ def _case(name):
             {}),
         "gf_encode_10_5": (
             gf_matmul._gf_matmul_padded,
-            [(_gbits(generator_matrix(10, 5)), f32), ((1024, 5, 1024), u8)],
+            [(_gbits(parity_matrix(10, 5)), f32), ((1024, 5, 1024), u8)],
             {}),
         "gf_encode_14_10": (
             gf_matmul._gf_matmul_padded,
-            [(_gbits(generator_matrix(14, 10)), f32), ((256, 10, lp), u8)],
+            [(_gbits(parity_matrix(14, 10)), f32), ((256, 10, lp), u8)],
             {}),
         "gf_decode_5_5": (
             gf_matmul._gf_matmul_padded,
@@ -103,13 +103,13 @@ def _case(name):
         # batches ran out of VMEM with messages on sublanes
         "fused_realtime": (
             ops._fused_ingest_pallas,
-            [(_gbits(generator_matrix(10, 5)), f32),
+            [(_gbits(parity_matrix(10, 5)), f32),
              ((4096, _sha1_blocks(rt.k * 1024), 16), u32), ((4096,), i32),
              ((4096, rt.k, 1024), u8)],
             {}),
         "fused_archival": (
             ops._fused_ingest_pallas,
-            [(_gbits(generator_matrix(14, 10)), f32),
+            [(_gbits(parity_matrix(14, 10)), f32),
              ((1024, _sha1_blocks(ar.k * lp), 16), u32), ((1024,), i32),
              ((1024, ar.k, lp), u8)],
             {}),
