@@ -349,13 +349,15 @@ class FusedEngine(KernelEngine):
     replaced by the fused SHA-1 + GF-encode dispatch
     (``kernels.ops.fused_hash_encode_blobs``): each chunk is packed into
     device-resident (B, k, L) form once and both passes run inside one
-    jitted launch per piece-length bucket, so a put window costs
+    jitted launch per piece-length bucket (several of ``FUSED_LANES``
+    lanes each for a bucket larger than that), so a put window costs
     1 gear + O(piece-length buckets) launches instead of
     1 gear + 1 SHA-1 + O(length buckets) GF.  Encoding is speculative --
     every unique chunk of the window is encoded before the dedup lookup
-    decides whether its pieces are needed -- which trades a few wasted
-    device FLOPs for the removed round-trip.  Byte-identical to the
-    staged path (differential-tested), and the store falls back to
+    decides whether its pieces are needed; ``SchedulerStats``
+    ``spec_encoded_bytes`` and ``spec_dropped_bytes`` count the bytes it
+    encodes and the part of them dedup throws away.  Byte-identical to
+    the staged path (differential-tested), and the store falls back to
     staged ``hash_chunks`` + ``encode_blobs_multi`` automatically when
     ``supports_fused_ingest`` is false (custom ``hash_fn``).
     """

@@ -40,8 +40,8 @@ def sha1_pad_blocks(data: bytes) -> np.ndarray:
     return words.reshape(-1, 16)
 
 
-def sha1_pad_batch(chunks: list[bytes], max_len: int | None = None
-                   ) -> tuple[np.ndarray, np.ndarray]:
+def sha1_pad_batch(chunks: list[bytes], max_len: int | None = None,
+                   exact: bool = False) -> tuple[np.ndarray, np.ndarray]:
     """Pad a batch of chunks to a common block count.
 
     Returns ``(blocks, n_blocks)`` where ``blocks`` is
@@ -60,6 +60,8 @@ def sha1_pad_batch(chunks: list[bytes], max_len: int | None = None
     129-block (8 KB-cap) message schedule through the compression loop
     for every lane; bucketing cuts that steady-state overhead without
     reopening the per-window retrace bug the fixed cap solved.
+    ``exact`` pads the block axis to the cap itself, for a caller whose
+    launch shape must not follow the batch's longest chunk.
     """
     padded = [sha1_pad_blocks(c) for c in chunks]
     counts = np.array([p.shape[0] for p in padded], dtype=np.int32)
@@ -70,7 +72,7 @@ def sha1_pad_batch(chunks: list[bytes], max_len: int | None = None
             raise ValueError(
                 f"chunk needs {cap} SHA-1 blocks > fixed cap {fixed} "
                 f"(max_len={max_len}); raise the caller's max_len")
-        cap = min(1 << (cap - 1).bit_length(), fixed)
+        cap = fixed if exact else min(1 << (cap - 1).bit_length(), fixed)
     out = np.zeros((len(chunks), cap, 16), dtype=np.uint32)
     for i, p in enumerate(padded):
         out[i, : p.shape[0]] = p

@@ -204,12 +204,16 @@ class Sanitizer:
         if staged_hash_only:
             self.add_budget(sha1=sha1)
             return
-        buckets = {
-            (code.n, code.k,
-             -(-code.piece_len(len(blob)) // self._quantum))
-            for code, blob in zip(codes, chunks)}
+        buckets: dict[tuple[int, int, int], int] = {}
+        for code, blob in zip(codes, chunks):
+            key = (code.n, code.k,
+                   -(-code.piece_len(len(blob)) // self._quantum))
+            buckets[key] = buckets.get(key, 0) + 1
         if getattr(engine, "supports_fused_ingest", False):
-            self.add_budget(fused=len(buckets))
+            # a bucket past FUSED_LANES chunks runs as several launches
+            from repro.kernels.ops import FUSED_LANES
+            self.add_budget(fused=sum(-(-n // FUSED_LANES)
+                                      for n in buckets.values()))
         else:
             self.add_budget(sha1=sha1, gf=len(buckets))
 

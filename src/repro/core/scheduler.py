@@ -309,6 +309,11 @@ class SchedulerStats:
     # bytes the foreground windows moved (kernels.launches.TRANSFERS)
     h2d_bytes: int = 0
     d2h_bytes: int = 0
+    # chunk bytes a fused engine hashed and RS-encoded before dedup, and
+    # the part of them no upload took (kernels.launches.SPECULATION);
+    # both stay 0 on a staged engine
+    spec_encoded_bytes: int = 0
+    spec_dropped_bytes: int = 0
 
     @property
     def data_plane_launches(self) -> int:
@@ -626,6 +631,8 @@ class BatchScheduler:
                         getattr(self.stats, field) + secs)
         self.stats.h2d_bytes += delta["transfers"].h2d_bytes
         self.stats.d2h_bytes += delta["transfers"].d2h_bytes
+        self.stats.spec_encoded_bytes += delta["speculation"].encoded_bytes
+        self.stats.spec_dropped_bytes += delta["speculation"].dropped_bytes
         self._scrub_window()
         self._repair_window()
         self._writeback_window()

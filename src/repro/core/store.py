@@ -86,7 +86,7 @@ from repro.core.repair import RepairManager, RepairReport
 from repro.core.sanitizer import Sanitizer, SanitizerError  # noqa: F401
 from repro.core.shard import (ShardedBindingSlice, ShardedChunkIndex,
                               ShardedSwitchTable, ShardMap)
-from repro.kernels.launches import span
+from repro.kernels.launches import SPECULATION, span
 
 
 @dataclasses.dataclass
@@ -641,11 +641,12 @@ class SEARSStore:
         # each group's chunks are hashed AND speculatively RS-encoded in
         # the same device residency (one launch per piece-length bucket
         # per group); pieces for chunks the dedup pass later rejects are
-        # simply dropped.  Staged engines hash here and encode in
-        # _execute_uploads as before.  Chunk ids are per-chunk
-        # deterministic, so the grouping changes launch counts, never
-        # bytes.
+        # dropped, and SPECULATION counts both sides.  Staged engines
+        # hash here and encode in _execute_uploads as before.  Chunk ids
+        # are per-chunk deterministic, so the grouping changes launch
+        # counts, never bytes.
         precomputed: dict[tuple[int, int, bytes], list[bytes]] | None = None
+        kept: set[tuple[int, int, bytes]] = set()  # precomputed keys taken
         # under write-back the ack must not pay the encode: stage hashing
         # here and defer the GF work to the background drain, even on a
         # fused engine (its speculative hash+encode mega-kernel would
@@ -675,10 +676,13 @@ class SEARSStore:
                     if fused:
                         g_ids, g_pieces = self.engine.hash_encode_blobs_multi(
                             list(zip(g_codes, g_chunks)))
-                        precomputed.update(
-                            {(code.n, code.k, cid): pieces
-                             for code, cid, pieces in zip(g_codes, g_ids,
-                                                          g_pieces)})
+                        # the engine encodes each distinct job once
+                        sizes: dict[tuple[int, int, bytes], int] = {}
+                        for code, cid, chunk, pieces in zip(
+                                g_codes, g_ids, g_chunks, g_pieces):
+                            precomputed[(code.n, code.k, cid)] = pieces
+                            sizes[(code.n, code.k, cid)] = len(chunk)
+                        SPECULATION.encoded_bytes += sum(sizes.values())
                     else:
                         g_ids = self.engine.hash_chunks(g_chunks)
                     pos = 0
@@ -741,8 +745,8 @@ class SEARSStore:
                 if self._write_back:
                     fc, we = self._commit_writeback(g_plans)
                 else:
-                    fc, we = self._execute_uploads(g_plans,
-                                                   precomputed=precomputed)
+                    fc, we = self._execute_uploads(
+                        g_plans, precomputed=precomputed, kept=kept)
             except Exception as exc:
                 # encode-batch failure: this group's reservations are
                 # already released; release the not-yet-executed groups'
@@ -887,7 +891,8 @@ class SEARSStore:
                           encode_tasks=tasks, entries=entries,
                           request_id=request_id, storage_class=cls.name)
 
-    def _execute_uploads(self, plans: list[UploadPlan], precomputed=None
+    def _execute_uploads(self, plans: list[UploadPlan], precomputed=None,
+                         kept: set | None = None
                          ) -> tuple[set[tuple[bytes, int]], Exception | None]:
         """Data plane: batched RS encode + bulk per-cluster piece writes.
 
@@ -898,15 +903,18 @@ class SEARSStore:
         a fused hash+encode pass already produced; tasks found there skip
         the encode batch entirely (with a fused engine that is every live
         task, so ``encode_blobs_multi`` sees an empty job list and issues
-        nothing).  Returns ``(failed_copies, error)``: the (chunk_id,
-        cluster_id) copies whose pieces could not be stored (dead-node
-        writes) and the first write error, so the caller can demux the
-        failure back to the requests that reference those copies.
-        Cluster writes are independent -- one failing cluster never
-        aborts the others.  An encode-batch failure raises (after
+        nothing).  ``kept`` collects the precomputed keys a task took,
+        each counted once in ``SPECULATION.kept_bytes`` across the
+        window's groups.  Returns ``(failed_copies, error)``: the
+        (chunk_id, cluster_id) copies whose pieces could not be stored
+        (dead-node writes) and the first write error, so the caller can
+        demux the failure back to the requests that reference those
+        copies.  Cluster writes are independent -- one failing cluster
+        never aborts the others.  An encode-batch failure raises (after
         releasing all reservations).
         """
         pre = precomputed or {}
+        kept = set() if kept is None else kept
         # landing the pieces, around the encode: which new chunk copies
         # are still live, their reservations, then the per-cluster writes
         with span("sears.put.write"):
@@ -929,9 +937,13 @@ class SEARSStore:
             to_encode = []
             for i, t in enumerate(live):
                 code = self.clusters[t.cluster_id].code
-                hit = pre.get((code.n, code.k, t.chunk_id))
+                key = (code.n, code.k, t.chunk_id)
+                hit = pre.get(key)
                 if hit is not None:
                     ready[i] = hit
+                    if key not in kept:
+                        kept.add(key)
+                        SPECULATION.kept_bytes += len(t.data)
                 else:
                     to_encode.append((i, t))
         try:

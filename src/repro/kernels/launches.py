@@ -5,14 +5,16 @@ benchmarks) can read the counters without importing jax and the Pallas
 kernel modules — a NumpyEngine store never pays that import just to
 snapshot counts that stay zero on its path.
 
-Three families, each with ``snapshot()``/``delta()``:
+Four families, each with ``snapshot()``/``delta()``:
 
 * ``LAUNCHES``/``TRACES`` — data-plane dispatches and retraces;
 * ``SPANS`` — host seconds and entries per ``sears.*`` step, filled by
   :func:`span`, which also marks the step on the ``jax.profiler``
   timeline (only once jax is loaded);
 * ``TRANSFERS`` — bytes shipped host→device at each dispatch and
-  device→host at each materialization (:func:`to_host`).
+  device→host at each materialization (:func:`to_host`);
+* ``SPECULATION`` — chunk bytes the fused ingest encoded before dedup,
+  and how many of them an upload then kept.
 """
 
 from __future__ import annotations
@@ -155,6 +157,39 @@ class TransferCounter:
 TRANSFERS = TransferCounter()
 
 
+@dataclasses.dataclass
+class SpeculationCounter:
+    """Chunk bytes RS-encoded before the dedup pass decided on them.
+
+    A fused engine hashes and encodes every distinct ``(code, chunk)``
+    job of a put window together (``encoded_bytes``); the store counts in
+    ``kept_bytes`` the jobs whose pieces an upload task then took.  The
+    rest, ``dropped_bytes``, were encoded for nothing: dedup hits on a
+    stored copy, or chunks a later request in the window deleted.
+    """
+
+    encoded_bytes: int = 0
+    kept_bytes: int = 0
+
+    @property
+    def dropped_bytes(self) -> int:
+        return self.encoded_bytes - self.kept_bytes
+
+    def snapshot(self) -> "SpeculationCounter":
+        return dataclasses.replace(self)
+
+    def delta(self, since: "SpeculationCounter") -> "SpeculationCounter":
+        return SpeculationCounter(
+            encoded_bytes=self.encoded_bytes - since.encoded_bytes,
+            kept_bytes=self.kept_bytes - since.kept_bytes)
+
+    def reset(self) -> None:
+        self.encoded_bytes = self.kept_bytes = 0
+
+
+SPECULATION = SpeculationCounter()
+
+
 def shipped(*arrays) -> None:
     """Count the host arrays a dispatch copies to the device (an array
     already on the device costs nothing)."""
@@ -189,13 +224,15 @@ def reset_all() -> None:
     TRACES.reset()
     SPANS.reset()
     TRANSFERS.reset()
+    SPECULATION.reset()
 
 
 def snapshot_all() -> dict:
     """Point-in-time snapshot of every family, keyed 'launches'/'traces'/
-    'spans'/'transfers'."""
+    'spans'/'transfers'/'speculation'."""
     return {"launches": LAUNCHES.snapshot(), "traces": TRACES.snapshot(),
-            "spans": SPANS.snapshot(), "transfers": TRANSFERS.snapshot()}
+            "spans": SPANS.snapshot(), "transfers": TRANSFERS.snapshot(),
+            "speculation": SPECULATION.snapshot()}
 
 
 def delta_all(since: dict) -> dict:
@@ -203,4 +240,5 @@ def delta_all(since: dict) -> dict:
     return {"launches": LAUNCHES.delta(since["launches"]),
             "traces": TRACES.delta(since["traces"]),
             "spans": SPANS.delta(since["spans"]),
-            "transfers": TRANSFERS.delta(since["transfers"])}
+            "transfers": TRANSFERS.delta(since["transfers"]),
+            "speculation": SPECULATION.delta(since["speculation"])}

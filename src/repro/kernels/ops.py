@@ -362,6 +362,14 @@ def sha1_digest_words(blocks, counts, impl: str = "kernel") -> jnp.ndarray:
 # path's separate SHA-1 launch + GF launch with a host round-trip between
 # them.  Counted in ``LAUNCHES.fused`` (neither .sha1 nor .gf ticks).
 
+# Lanes of one fused launch once a bucket outgrows it.  A 64 MiB window
+# of 4 KiB-average chunks puts thousands of chunks in a bucket; padded to
+# the next power of two, a count that hovers near one compiles a rare
+# shape in the middle of a steady stream of windows.  Launches of exactly
+# this many lanes keep one shape per bucket however full the window is.
+FUSED_LANES = 1024
+
+
 @jax.jit
 def _fused_ingest_ref(Mdev: jnp.ndarray, blocks: jnp.ndarray,
                       counts: jnp.ndarray, data: jnp.ndarray):
@@ -384,15 +392,18 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
                             ) -> tuple[list[bytes], list[list[bytes]]]:
     """Fused SHA-1 + RS encode of a blob batch -> (ids, pieces per blob).
 
-    Blobs are bucketed by padded piece length exactly like
-    ``rs_encode_blobs`` (quantum TILE_L, power-of-two batch), so a window
-    costs O(length buckets) fused launches; the SHA-1 message schedule is
-    capped at ``k * Lp`` bytes per bucket -- every blob of the bucket
-    fits by construction (``piece_len(len) <= Lp``), so there is no
-    oversized-chunk fallback on this path.  Like the staged encode it
-    computes and copies back only the parity and unpacks with the same
-    ``rs_code.unpack_encoded``: byte-identical to running
-    ``sha1_digests`` and ``rs_encode_blobs`` separately.
+    Blobs are bucketed by padded piece length like ``rs_encode_blobs``
+    (quantum TILE_L).  A bucket of up to ``FUSED_LANES`` blobs is one
+    launch with its batch padded to a power of two; a larger one runs
+    as launches of exactly ``FUSED_LANES`` lanes, so a window costs
+    O(length buckets x chunks / FUSED_LANES) fused launches of a bounded
+    shape set.  The SHA-1 message schedule is ``k * Lp`` bytes per
+    bucket -- every blob of the bucket fits by construction
+    (``piece_len(len) <= Lp``), so there is no oversized-chunk fallback
+    on this path.  Like the staged encode it computes and copies back
+    only the parity and unpacks with the same ``rs_code.unpack_encoded``:
+    byte-identical to running ``sha1_digests`` and ``rs_encode_blobs``
+    separately.
     """
     from repro.core import rs_code
     from repro.kernels.gf_matmul import TILE_L
@@ -405,9 +416,13 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
         buckets = rs_code.bucket_by_piece_len(piece_lens, TILE_L)
     ids: list[bytes | None] = [None] * len(blobs)
     pieces: list[list[bytes] | None] = [None] * len(blobs)
+    launches = []
     for Lp, idxs in buckets.items():
+        Bp = min(_pow2(len(idxs)), FUSED_LANES)
+        launches += [(Lp, Bp, idxs[lo:lo + Bp])
+                     for lo in range(0, len(idxs), Bp)]
+    for Lp, Bp, idxs in launches:
         with span("sears.engine.pack"):
-            Bp = _pow2(len(idxs))
             data = np.zeros((Bp, code.k, Lp), dtype=np.uint8)
             group: list[bytes] = []
             for row, i in enumerate(idxs):
@@ -415,8 +430,8 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
                                               piece_lens[i], Lp)
                 group.append(blobs[i])
             group += [b""] * (Bp - len(idxs))
-            blocks, counts = hashing.sha1_pad_batch(group,
-                                                    max_len=code.k * Lp)
+            blocks, counts = hashing.sha1_pad_batch(
+                group, max_len=code.k * Lp, exact=True)
         LAUNCHES.fused += 1
         with span("sears.engine.dispatch"):
             shipped(blocks, counts, data)

@@ -194,6 +194,37 @@ def test_fused_window_launch_counts():
         f"({staged.total})"
 
 
+def test_fused_bucket_past_the_lane_cap_runs_equal_launches(monkeypatch):
+    """A piece-length bucket with more chunks than ``FUSED_LANES`` runs as
+    launches of exactly ``FUSED_LANES`` lanes at the bucket's SHA-1 cap:
+    one shape however many chunks the window holds, and the same ids and
+    pieces as the host."""
+    from repro.core.hashing import chunk_id
+    from repro.core.rs_code import RSCode
+    from repro.kernels import ops
+    from repro.kernels.launches import LAUNCHES
+
+    code = RSCode(10, 5)
+    # 11 chunks of the 512-byte bucket (1..2560 bytes under (10, 5))
+    lengths = np.random.default_rng(90).integers(1024, 2561, 11)
+    blobs = [_data(int(n), seed=100 + i) for i, n in enumerate(lengths)]
+    shapes = []
+    fused = ops._fused_ingest_ref
+
+    def record(*args):
+        shapes.append(tuple(np.shape(a) for a in args[1:]))
+        return fused(*args)
+    monkeypatch.setattr(ops, "_fused_ingest_ref", record)
+    monkeypatch.setattr(ops, "FUSED_LANES", 4)
+    before = LAUNCHES.snapshot()
+    ids, pieces = ops.fused_hash_encode_blobs(code, blobs, impl="ref")
+    assert LAUNCHES.delta(before).fused == 3
+    cap = (code.k * 512 + 9 + 63) // 64
+    assert set(shapes) == {((4, cap, 16), (4,), (4, code.k, 512))}
+    assert ids == [chunk_id(b) for b in blobs]
+    assert pieces == [code.encode_bytes(b) for b in blobs]
+
+
 def test_fused_steady_state_no_retrace():
     """Repeated put windows of the same shape must not retrace the fused
     jit entries (the per-window recompile failure mode)."""

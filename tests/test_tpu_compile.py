@@ -99,13 +99,14 @@ def _case(name):
             sha1._sha1_padded,
             [((512, _sha1_blocks(16384), 16), u32), ((512, 1), i32)],
             {"tile": sha1.TILE_B}),
-        # a 64 MiB window's bucket holds thousands of chunks: these
-        # batches ran out of VMEM with messages on sublanes
+        # a 64 MiB window's bucket holds thousands of chunks, cut into
+        # launches of FUSED_LANES: such batches ran out of VMEM with
+        # messages on sublanes
         "fused_realtime": (
             ops._fused_ingest_pallas,
             [(_gbits(parity_matrix(10, 5)), f32),
-             ((4096, _sha1_blocks(rt.k * 1024), 16), u32), ((4096,), i32),
-             ((4096, rt.k, 1024), u8)],
+             ((ops.FUSED_LANES, _sha1_blocks(rt.k * lp), 16), u32),
+             ((ops.FUSED_LANES,), i32), ((ops.FUSED_LANES, rt.k, lp), u8)],
             {}),
         "fused_archival": (
             ops._fused_ingest_pallas,
