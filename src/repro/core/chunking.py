@@ -183,8 +183,8 @@ class PendingSpans:
     Produced by ``chunk_spans_batch_begin``: the window's stream is
     concatenated and its candidate pass *issued* (one device gear launch
     on the kernel path); ``handle`` is whatever the issue function
-    returned -- an unmaterialized device bitmap, or a deferred host
-    closure.  ``chunk_spans_batch_finish`` resolves it to spans.
+    returned -- unmaterialized packed fire words on the device, or a
+    deferred host closure.  ``chunk_spans_batch_finish`` resolves it.
     """
 
     chunker: Chunker
@@ -199,8 +199,9 @@ def chunk_spans_batch_begin(chunker: Chunker, blobs: list[np.ndarray],
     """Issue the window's candidate pass without resolving it.
 
     ``issue_fn(stream, mask)`` dispatches the rolling-hash work and may
-    return an unmaterialized handle (e.g. an in-flight device fire
-    bitmap via ``kernels.ops.gear_fire_issue``); the host-side greedy
+    return an unmaterialized handle (e.g. the in-flight device fire
+    bitmap of ``kernels.ops.gear_fire_issue``, packed one bit per
+    position into uint32 words); the host-side greedy
     selection happens at ``chunk_spans_batch_finish``.  This is the
     double-buffering seam: window *i+1*'s gear launch runs while window
     *i*'s host phases (selection, dedup planning) execute.

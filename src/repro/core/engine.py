@@ -266,7 +266,7 @@ class KernelEngine(CodingEngine):
         self.hash_batch = hash_batch or self.HASH_BATCH
 
     def chunk_blobs_begin(self, chunker: Chunker, blobs: list[bytes]):
-        """Issue the window's gear launch; the bitmap stays on device."""
+        """Issue the window's gear launch; packed fire bits stay on device."""
         from repro.kernels import ops
         return chunking.chunk_spans_batch_begin(
             chunker, blobs,
@@ -274,7 +274,7 @@ class KernelEngine(CodingEngine):
                 stream, mask, impl=self.impl))
 
     def chunk_blobs_finish(self, pending) -> list[list[tuple[int, int]]]:
-        """Block on the fire bitmap; greedy selection on host."""
+        """Block on the packed fire bits; greedy selection on host."""
         from repro.kernels import ops
         return chunking.chunk_spans_batch_finish(
             pending, ops.gear_fire_resolve)

@@ -7,11 +7,11 @@ The sequential gear recurrence is a linear recurrence, so the hash is a
 
 The stream is laid out (rows, 128) -- byte t at row t // 128, lane
 t % 128 -- and each grid cell computes the hashes of ROWS rows (TILE
-bytes).  Pallas BlockSpecs cannot express halos directly, so the kernel
-receives the data *twice* with shifted index maps -- the current tile and
-the block of rows before it -- and takes its history from that block's
-tail (zeroed for the first tile, matching the reference's implicit
-zero-history).
+bytes; STEP_TILES tiles in the fire kernel).  Pallas BlockSpecs cannot
+express halos directly, so the kernel receives the data *twice* with
+shifted index maps -- the current cell and the block of rows before it
+-- and takes its history from that block's tail (zeroed for the first
+cell, matching the reference's implicit zero-history).
 
 The gear-table lookup is a lane gather: the 256-entry table is held as
 two 128-lane rows and each byte picks its lane from the row its top bit
@@ -19,7 +19,7 @@ selects (Mosaic lowers this 2-D, same-shape gather, not a 1-D
 ``jnp.take``).  The window sum is built by doubling (a 2-tap sum, then
 4, ..., 32) with lane and sublane rotations, so no slice is unaligned.
 All of it runs in VMEM: HBM traffic is the tile and its history block
-read (TILE + HALO_BLOCK * 128 bytes per cell) plus the output.
+read (the cell + HALO_BLOCK * 128 bytes) plus the output.
 """
 
 from __future__ import annotations
@@ -40,6 +40,11 @@ LANES = 128  # stream bytes per row
 ROWS = TILE // LANES  # rows per grid cell
 HALO_BLOCK = 32  # rows of the history block (the uint8 sublane tile)
 HALO_ROWS = 8  # of which the kernel looks up the last (>= 31 bytes)
+WORD_BITS = 32  # fire flags packed per uint32 word
+WORDS = ROWS // WORD_BITS  # packed fire rows per tile
+# tiles per grid step of the fire kernel: a step costs ~0.5 us besides
+# its work on a v5e, as much as ~5 tiles of hashing
+STEP_TILES = 4
 # bit-identical int32 reinterpret, split into the table's two lane rows
 _GEAR_ROWS = GEAR_TABLE.view(np.int32).reshape(2, LANES)
 
@@ -110,14 +115,14 @@ def _shift(a, s: int):
 
 
 def _hash_tile(p, cur_ref, prev_ref, gear_ref):
-    """Shared kernel body: the (ROWS, LANES) gear hashes of grid cell ``p``.
+    """Shared kernel body: the (rows, LANES) gear hashes of grid cell ``p``.
 
     The 32-tap sum is built by doubling: after the step of shift s each
     position holds the weighted sum of its last 2s gear values, so five
     shifts stand for the 32 taps.
     """
     gear = gear_ref[...]  # (2, LANES) int32: table entries 0-127, 128-255
-    g = _lookup(gear, cur_ref[...].astype(jnp.int32))  # (ROWS, LANES)
+    g = _lookup(gear, cur_ref[...].astype(jnp.int32))  # (rows, LANES)
     # history: the previous tile's last HALO_ROWS rows; only their last
     # 31 positions reach this tile, and what the doubling drags in from
     # before them (or from the row-0 wrap) stays inside the history rows
@@ -125,7 +130,7 @@ def _hash_tile(p, cur_ref, prev_ref, gear_ref):
     hist = _lookup(gear, prev)
     # first tile has no history: it contributes nothing
     hist = jnp.where(p == 0, jnp.uint32(0), hist)
-    a = jnp.concatenate([hist, g])  # (HALO_ROWS + ROWS, LANES)
+    a = jnp.concatenate([hist, g])  # (HALO_ROWS + rows, LANES)
     s = 1
     while s < WINDOW:
         a = a + (_shift(a, s) << jnp.uint32(s))
@@ -134,24 +139,38 @@ def _hash_tile(p, cur_ref, prev_ref, gear_ref):
 
 
 def _fire_kernel(cur_ref, prev_ref, gear_ref, mask_ref, out_ref):
-    """Fused hash + boundary test: emit the fire bitmap, not the hashes.
+    """Fused hash + boundary test, packed to one bit per position.
 
-    The mask test runs on the still-VMEM-resident hash vector, so only a
-    1-byte-per-position bool bitmap ships back to the host instead of the
-    4-byte uint32 hash array (the staged path's round-trip).
+    The mask test runs on the still-VMEM-resident hash vector and each
+    tile's (ROWS, LANES) fire flags are packed there into WORDS uint32
+    rows: bit j of word [r, l] is the flag of row WORD_BITS * r + j,
+    lane l.  So 1/8 byte per position ships back instead of the 4-byte
+    uint32 hash array (the staged path's round-trip).
     """
     h = _hash_tile(pl.program_id(0), cur_ref, prev_ref, gear_ref)
-    out_ref[...] = (h & mask_ref[...][0]) == 0
+    fire = (h & mask_ref[...][0]) == 0
+    bit = jnp.int32(1) << jax.lax.broadcasted_iota(
+        jnp.int32, (WORD_BITS, LANES), 0)
+    for t in range(out_ref.shape[0]):
+        for r in range(WORDS):
+            lo = (t * WORDS + r) * WORD_BITS
+            # distinct powers of two: the (wrapping int32) sum is their
+            # bitwise or; Mosaic reduces signed integers only
+            word = jnp.sum(jnp.where(fire[lo:lo + WORD_BITS], bit, 0),
+                           axis=0, keepdims=True)
+            out_ref[t, r:r + 1, :] = jax.lax.bitcast_convert_type(
+                word, jnp.uint32)
 
 
-def _stream_specs():
-    """BlockSpecs of the (rows, LANES) stream, its history block and the
-    table; the history block of cell 0 is clamped to block 0 and masked."""
-    per_tile = ROWS // HALO_BLOCK
+def _stream_specs(rows: int = ROWS):
+    """BlockSpecs of the stream in cells of ``rows`` rows, its history
+    block and the table; the history block of cell 0 is clamped to block
+    0 and masked."""
+    per_cell = rows // HALO_BLOCK
     return [
-        pl.BlockSpec((ROWS, LANES), lambda p: (p, 0)),
+        pl.BlockSpec((rows, LANES), lambda p: (p, 0)),
         pl.BlockSpec((HALO_BLOCK, LANES),
-                     lambda p: (jnp.maximum(p * per_tile - 1, 0), 0)),
+                     lambda p: (jnp.maximum(p * per_cell - 1, 0), 0)),
         pl.BlockSpec((2, LANES), lambda p: (0, 0)),
     ]
 
@@ -161,38 +180,63 @@ def _gear_fire_padded(data: jnp.ndarray, gear: jnp.ndarray,
                       mask: jnp.ndarray, *,
                       interpret: bool) -> jnp.ndarray:
     TRACES.gear += 1  # trace-time only: one increment per compiled shape
-    n = data.shape[0]
+    tiles = data.shape[0] // TILE
+    step = min(STEP_TILES, tiles)  # a bucket's tile count is a power of 2
     rows = data.reshape(-1, LANES)
     return pl.pallas_call(
         _fire_kernel,
-        grid=(n // TILE,),
-        in_specs=[*_stream_specs(),
+        grid=(tiles // step,),
+        in_specs=[*_stream_specs(step * ROWS),
                   pl.BlockSpec((1,), lambda p: (0,))],
-        out_specs=pl.BlockSpec((ROWS, LANES), lambda p: (p, 0)),
-        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.bool_),
+        out_specs=pl.BlockSpec((step, WORDS, LANES), lambda p: (p, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((tiles, WORDS, LANES), jnp.uint32),
         interpret=interpret,
         # the kernel's instruction name in a device trace, kept if this
         # wrapper is renamed (the benchmark's rooflines match it)
         name="_gear_fire_padded",
-    )(rows, rows, gear, mask).reshape(n)
+    )(rows, rows, gear, mask)
+
+
+def fire_tiles(n: int) -> int:
+    """Tiles whose packed words hold the fire flags of ``n`` bytes."""
+    return -(-n // TILE)
 
 
 def gear_fire(data, mask, *, interpret: bool) -> jnp.ndarray:
-    """(N,) uint8 + boundary mask -> (N,) bool fire bitmap (one launch).
+    """(N,) uint8 + boundary mask -> packed fire words (one launch).
 
     The fused twin of :func:`gear_hash`: hash and mask test both run on
-    device, so the result is the boolean candidate bitmap (pad positions
-    are sliced off like the hash path).  Returns the *device* array
-    unmaterialized -- callers overlap host work with the launch and
-    compact to positions with ``np.flatnonzero`` when they resolve it.
+    device, and the result is the candidate bitmap packed one bit per
+    position, ``(fire_tiles(N), WORDS, LANES)`` uint32 (layout in
+    ``_fire_kernel``); only the tiles that hold the N positions are
+    kept, whose tail may still flag pad positions >= N.  Returns the
+    *device* array unmaterialized -- callers overlap host work with the
+    launch and decode it with ``fire_positions`` when they resolve it.
     """
     data = jnp.asarray(data, jnp.uint8)
     n = data.shape[0]
     if n == 0:
-        return jnp.zeros((0,), jnp.bool_)
+        return jnp.zeros((0, WORDS, LANES), jnp.uint32)
     mask_arr = jnp.asarray([mask], jnp.uint32)
     return _gear_fire_padded(pad_to_bucket(data), _device_gear_table(),
-                             mask_arr, interpret=interpret)[:n]
+                             mask_arr, interpret=interpret)[:fire_tiles(n)]
+
+
+def fire_positions(words: np.ndarray, n: int) -> np.ndarray:
+    """Packed fire words (host) -> sorted int64 positions below ``n``.
+
+    Only the nonzero words are expanded to their bits, so the cost
+    follows the number of fires, not the stream length.
+    """
+    flat = words.reshape(-1)
+    nz = np.flatnonzero(flat)
+    bits = np.unpackbits(flat[nz].astype("<u4").view(np.uint8).reshape(
+        -1, 4), axis=1, bitorder="little")  # (nonzero words, WORD_BITS)
+    word, j = np.nonzero(bits)
+    w = nz[word]
+    tile, r, lane = w // (WORDS * LANES), w // LANES % WORDS, w % LANES
+    pos = tile * TILE + (r * WORD_BITS + j) * LANES + lane
+    return np.sort(pos[pos < n])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -210,56 +210,70 @@ def gear_hash_stream(data, impl: str = "kernel") -> np.ndarray:
 
 @jax.jit
 def _gear_fire_ref(data: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
-    """Jit-cached fused gear hash + boundary mask test -> (N,) bool."""
+    """Jit-cached fused gear hash + boundary mask test -> packed fire words.
+
+    The kernel's output format (``gear_cdc._fire_kernel``): per TILE of
+    the padded stream, (WORDS, LANES) uint32 words whose bit j of word
+    [r, l] flags row WORD_BITS * r + j, lane l.
+    """
     TRACES.gear += 1  # trace-time only: one increment per compiled shape
-    return (ref.gear_hash_ref(data) & mask) == 0
+    fire = ((ref.gear_hash_ref(data) & mask) == 0).astype(jnp.uint32)
+    fire = fire.reshape(-1, gear_cdc.WORDS, gear_cdc.WORD_BITS,
+                        gear_cdc.LANES)
+    bit = jnp.arange(gear_cdc.WORD_BITS, dtype=jnp.uint32)[:, None]
+    return jnp.sum(fire << bit, axis=2, dtype=jnp.uint32)
 
 
 def gear_fire_issue(data, mask, impl: str = "kernel"):
     """Dispatch one fused gear hash + mask launch; the result stays on device.
 
-    Returns the unmaterialized (N,) bool fire bitmap (``None`` for an
-    empty stream).  JAX dispatch is async, so the caller is free to do
-    host work -- greedy boundary selection of the *previous* window,
-    plan building -- while the launch runs; ``gear_fire_resolve``
-    blocks on and compacts the bitmap when it is actually needed.  Both
-    the Pallas kernel (``gear_cdc.gear_fire``) and the jitted ref oracle
-    fuse the mask test into the launch, so the full uint32 hash array
-    never round-trips to the host.
+    Returns ``(words, n)``: the unmaterialized packed fire bitmap of the
+    n-byte stream, one bit per position in the tiles that hold it
+    (``gear_cdc.gear_fire``), or ``None`` for an empty stream.  JAX
+    dispatch is async, so the caller is free to do host work -- greedy
+    boundary selection of the *previous* window, plan building -- while
+    the launch runs; ``gear_fire_resolve`` blocks on the words and
+    decodes them when they are needed.  Both the Pallas kernel and the
+    jitted ref oracle fuse the mask test and the packing into the
+    launch, so 1/8 byte per position comes back, never the uint32 hash
+    array.
     """
     data = np.asarray(data, np.uint8)
-    if data.shape[0] == 0:
+    n = data.shape[0]
+    if n == 0:
         return None
     LAUNCHES.gear += 1
     mask = np.uint32(mask)
     with span("sears.engine.dispatch"):
         if impl == "ref":
-            n = data.shape[0]
             padded = gear_cdc.pad_to_bucket(data)
             shipped(padded, mask)
-            return _gear_fire_ref(padded, jnp.uint32(mask))[:n]
+            words = _gear_fire_ref(padded, jnp.uint32(mask))
+            return words[:gear_cdc.fire_tiles(n)], n
         shipped(data, mask)
-        return gear_cdc.gear_fire(data, mask, interpret=not _on_tpu())
+        return gear_cdc.gear_fire(data, mask, interpret=not _on_tpu()), n
 
 
-def gear_fire_resolve(fire) -> np.ndarray:
-    """Materialize an issued fire bitmap -> sorted candidate positions."""
-    if fire is None:
+def gear_fire_resolve(issued) -> np.ndarray:
+    """Materialize issued fire words -> sorted candidate positions."""
+    if issued is None:
         return np.zeros(0, np.int64)
-    fire = to_host(fire)
+    words, n = issued
+    words = to_host(words)
     with span("sears.engine.unpack"):
-        return np.flatnonzero(fire).astype(np.int64)
+        return gear_cdc.fire_positions(words, n)
 
 
 def gear_candidate_positions(data, mask, impl: str = "kernel") -> np.ndarray:
     """One gear launch over an ingest stream -> sorted candidate positions.
 
-    The device twin of ``chunking.gear_candidates_np``: the 32-tap hash
-    and the boundary mask test run fused on the device (one bucketed
-    launch, bool fire bitmap shipped back instead of the 4-byte-per-
-    position hash array); the sparse ``flatnonzero`` compaction stays on
-    the host.  ``gear_fire_issue``/``gear_fire_resolve`` split the same
-    work for callers that overlap host work with the launch.
+    The device twin of ``chunking.gear_candidates_np``: the 32-tap hash,
+    the boundary mask test and the packing of the flags to one bit per
+    position run fused on the device (one bucketed launch; 1/8 byte per
+    position shipped back instead of the 4-byte hash array); decoding
+    the few nonzero words to positions stays on the host.
+    ``gear_fire_issue``/``gear_fire_resolve`` split the same work for
+    callers that overlap host work with the launch.
     """
     return gear_fire_resolve(gear_fire_issue(data, mask, impl=impl))
 

@@ -104,8 +104,10 @@ def test_gear_kernel_pinned_to_refs(n):
     np.testing.assert_array_equal(h, np.asarray(ref.gear_hash_ref(data)))
     np.testing.assert_array_equal(h[:40], gear_hash_sequential(data[:40]))
     mask = np.uint32((1 << 9) - 1)
-    fire = np.asarray(gear_cdc.gear_fire(data, mask, interpret=True))
-    np.testing.assert_array_equal(np.flatnonzero(fire),
+    words = np.asarray(gear_cdc.gear_fire(data, mask, interpret=True))
+    assert words.shape == (gear_cdc.fire_tiles(n), gear_cdc.WORDS,
+                           gear_cdc.LANES)
+    np.testing.assert_array_equal(gear_cdc.fire_positions(words, n),
                                   gear_candidates_np(data, mask))
 
 

@@ -140,9 +140,10 @@ def test_h2d_bytes_equal_the_launched_shapes(monkeypatch):
         assert back == data[0] * (CLASS.n - CLASS.k) * data[2]
         h2d += int(np.prod(data))  # the coding matrix stays on the device
     assert moved.h2d_bytes == h2d
-    # back: one fire flag per stream byte, 5 digest words per hash lane,
-    # n-k parity rows per GF lane
-    d2h = stream + sum(b for name, _, b in seen if b is not None)
+    # back: one fire bit per stream byte (in whole tiles), 5 digest words
+    # per hash lane, n-k parity rows per GF lane
+    d2h = gear_cdc.fire_tiles(stream) * gear_cdc.TILE // 8
+    d2h += sum(b for name, _, b in seen if b is not None)
     assert moved.d2h_bytes == d2h
 
 

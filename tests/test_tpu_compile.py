@@ -130,6 +130,19 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()  # a Mosaic kernel
 
 
+def test_gear_fire_returns_one_bit_per_position_unpadded(one_chip):
+    fn, shapes, static = _case("gear_fire_64MiB")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = fn.lower(*args, interpret=False, **static).compile()
+    n = shapes[0][0][0]
+    out = compiled.out_info
+    assert out.shape == (n // gear_cdc.TILE, gear_cdc.WORDS, gear_cdc.LANES)
+    assert out.dtype == jnp.uint32
+    # the device holds the packed words without tile padding: n / 8 bytes
+    assert compiled.memory_analysis().output_size_in_bytes == n // 8
+
+
 # the instruction each kernel shows in a device trace, whatever the name
 # of the jitted wrapper around it (the benchmark's rooflines match these)
 TRACE_NAMES = {"gear_fire_64MiB": ["_gear_fire_padded"],
@@ -144,7 +157,8 @@ def test_kernel_instruction_is_named_by_its_pallas_call(name, one_chip):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
     text = fn.lower(*args, interpret=False, **static).compile().as_text()
-    calls = [line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+    calls = [line.split(" = ", 1)[0].strip().removeprefix("ROOT ")
+             .lstrip("%").rsplit(".", 1)[0]
              for line in text.splitlines()
              if "custom_call_target=\"tpu_custom_call\"" in line]
     assert sorted(calls) == sorted(TRACE_NAMES[name])
