@@ -1,4 +1,4 @@
-"""Shared benchmark machinery: store builders, workload ingestion, replay."""
+"""Shared paper-figure machinery: store builders, workload ingestion, replay."""
 
 from __future__ import annotations
 
@@ -7,7 +7,6 @@ import dataclasses
 import numpy as np
 
 from repro.core.classes import StorageClass
-from repro.kernels import launches
 from repro.core.latency import LatencyParams, calibrate
 from repro.core.radmad import RADMADStore
 from repro.core.store import SEARSStore
@@ -27,7 +26,7 @@ def calibrated_params() -> LatencyParams:
 
 def make_store(scheme: str, n: int = 10, k: int = 5, clusters: int = 20,
                node_capacity: int = 2 << 30, seed: int = 0,
-               engine: str = "numpy", shards: int = 1):
+               engine: str = "numpy"):
     lat = calibrated_params()
     if scheme == "radmad":
         # paper: 8 MB containers at full scale; scaled with the dataset
@@ -35,40 +34,12 @@ def make_store(scheme: str, n: int = 10, k: int = 5, clusters: int = 20,
                            node_capacity=node_capacity,
                            container_size=512 << 10, latency=lat, seed=seed)
     cls = StorageClass(name="default", n=n, k=k, binding=scheme)
-    # sanitize=False even under SEARS_SANITIZE=1: benches run many stores
-    # (and deliberate per-chunk baseline arms) over the process-global
-    # LAUNCHES counters, outside the sanitizer's single-store launch model
+    # sanitize=False even under SEARS_SANITIZE=1: the figures run many
+    # stores over the process-global LAUNCHES counters, outside the
+    # sanitizer's single-store launch model
     return SEARSStore(classes=[cls], num_clusters=clusters,
                       node_capacity=node_capacity, sanitize=False,
-                      latency=lat, seed=seed, engine=engine, shards=shards)
-
-
-def warm_start(engine: str, clusters: int = 4) -> None:
-    """Warm an engine's global jit caches on a throwaway store.
-
-    Runs a small put, a healthy get and a degraded get (non-systematic
-    decode) so the gear/SHA-1/GF/fused jit entries for the common launch
-    shapes are compiled before any timed pass.  Benchmarks that report
-    steady-state numbers call this once per engine spec instead of each
-    re-deriving its own warmup traffic; the caches are process-global, so
-    the throwaway store is enough.
-    """
-    store = make_store("ulb", clusters=clusters, engine=engine)
-    rng = np.random.default_rng(11)
-    files = [(f"warm{i}",
-              rng.integers(0, 256, size=24 << 10, dtype=np.int64)
-              .astype(np.uint8).tobytes())
-             for i in range(3)]
-    store.put_files("warm", files)
-    names = [fn for fn, _ in files]
-    store.get_files("warm", names)
-    for c in store.clusters:
-        c.kill_nodes(list(range(0, store.n, 2))[: store.n - store.k])
-    store.get_files("warm", names)
-    # start every timed pass from zeroed counters in BOTH families: a
-    # bench that resets launches but reads warmup-era trace counts (or
-    # vice versa) would skew its retrace assertions
-    launches.reset_all()
+                      latency=lat, seed=seed, engine=engine)
 
 
 @dataclasses.dataclass

@@ -32,16 +32,19 @@ def main() -> None:
     print(f"re-upload: {st2.n_new_chunks} new chunks, "
           f"{st2.bytes_uploaded} bytes sent (dedup)")
 
-    # --- a streaming backlog: double-buffered put windows ----------------
-    backlog = [[("bob", [(f"batch{w}/part{i}",
-                          rng.integers(0, 256, size=60_000, dtype=np.int64)
-                          .astype(np.uint8).tobytes())
-                         for i in range(3)])]
-               for w in range(3)]
-    stats = store.put_windows_pipelined(backlog)
-    print(f"pipelined ingest: {len(stats)} windows, "
-          f"{sum(s.n_chunks for w in stats for s in w)} chunks "
-          f"(window i+1 chunks on device while window i plans on host)")
+    # --- a backlog through the scheduler: one shared put window ---------
+    # queued requests coalesce into flush windows that share one gear,
+    # one SHA-1 and one GF batch per length bucket across every request
+    sched = store.scheduler()
+    parts = [(f"batch{w}/part{i}",
+              rng.integers(0, 256, size=60_000, dtype=np.int64)
+              .astype(np.uint8).tobytes())
+             for w in range(3) for i in range(3)]
+    puts = [sched.submit_put("bob", [part]) for part in parts]
+    sched.flush()
+    print(f"scheduled ingest: {len(puts)} puts in "
+          f"{sched.stats.n_put_windows} window, "
+          f"{sum(s.n_chunks for f in puts for s in f.result())} chunks")
 
     # --- half the storage nodes die; the files survive -------------------
     for cluster in store.clusters:
@@ -51,11 +54,12 @@ def main() -> None:
     print(f"retrieval with 5/10 nodes dead: OK, modeled {rst.time_s:.2f}s "
           f"({rst.n_fetched} chunks from {rst.clusters_touched} cluster)")
 
-    # --- prefetched multi-file get: next window reads+decodes early ------
-    names = [f"batch{w}/part{i}" for w in range(3) for i in range(3)]
-    results = store.get_files_pipelined("bob", names, window_files=3)
-    assert all(len(data) == 60_000 for data, _ in results)
-    print(f"pipelined degraded get: {len(results)} files OK, "
+    # --- a degraded multi-file get window: one batched decode -----------
+    got = sched.submit_get("bob", [name for name, _ in parts])
+    sched.flush()
+    results = got.result()
+    assert [data for data, _ in results] == [blob for _, blob in parts]
+    print(f"scheduled degraded get: {len(results)} files OK, "
           f"mean modeled {np.mean([r.time_s for _, r in results]):.2f}s")
 
     # --- storage accounting ------------------------------------------------

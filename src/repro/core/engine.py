@@ -153,10 +153,10 @@ class CodingEngine(abc.ABC):
     # ``*_begin`` issues a window's device work (or defers host work) and
     # returns an opaque token; ``*_finish`` materializes results.  The
     # base defaults defer everything to finish time -- correct for any
-    # engine -- so the pipelined store paths work unchanged on
-    # ``NumpyEngine``; ``KernelEngine`` overrides them to genuinely issue
-    # launches ahead (JAX async dispatch), which is where the overlap
-    # comes from.
+    # engine -- so the scheduler's begin-ahead put windows work unchanged
+    # on ``NumpyEngine``; ``KernelEngine`` overrides them to genuinely
+    # issue launches ahead (JAX async dispatch), which is where the
+    # overlap comes from.
 
     def chunk_blobs_begin(self, chunker: Chunker, blobs: list[bytes]):
         """Stage a window's CDC pass; resolve with ``chunk_blobs_finish``."""
@@ -171,14 +171,6 @@ class CodingEngine(abc.ABC):
 
     def chunk_blobs_multi_finish(self, token) -> list[list[tuple[int, int]]]:
         return self.chunk_blobs_multi(token)
-
-    def decode_blobs_multi_begin(
-            self, jobs: list[tuple[RSCode, dict[int, bytes], int]]):
-        """Stage a decode window; resolve with ``decode_blobs_multi_finish``."""
-        return jobs
-
-    def decode_blobs_multi_finish(self, token) -> list[bytes]:
-        return self.decode_blobs_multi(token)
 
     def _by_policy_begin(self, jobs: list[tuple], begin_fn):
         """Begin-side half of ``_by_policy``: group by policy, issue one
@@ -290,18 +282,6 @@ class KernelEngine(CodingEngine):
 
     def chunk_blobs_multi_finish(self, token) -> list[list[tuple[int, int]]]:
         return self._by_policy_finish(token, self.chunk_blobs_finish)
-
-    def decode_blobs_multi_begin(
-            self, jobs: list[tuple[RSCode, dict[int, bytes], int]]):
-        """Issue decode launches per code; arrays stay unmaterialized."""
-        from repro.kernels import ops
-        return self._by_policy_begin(
-            jobs, lambda code, group: ops.rs_decode_blobs_begin(
-                code, group, impl=self.impl))
-
-    def decode_blobs_multi_finish(self, token) -> list[bytes]:
-        from repro.kernels import ops
-        return self._by_policy_finish(token, ops.rs_decode_blobs_finish)
 
     def hash_chunks(self, chunks: list[bytes]) -> list[bytes]:
         if self.hash_fn is not hashing.chunk_id:

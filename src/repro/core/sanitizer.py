@@ -8,8 +8,9 @@ Three checks, mirroring the searslint static passes at runtime:
    state (dedup index, switching tables, cluster/node occupancy,
    binding state, repair queue) before and after the call and raises
    :class:`SanitizerError` on any difference.  This is the runtime twin
-   of the PR 6 byte-identity proof: a begin that mutates state breaks
-   pipelined/sequential equivalence.
+   of the byte-identity proof: the scheduler issues put window i+1's
+   begin before window i finishes, so a begin that mutates state would
+   make a flush differ from sequential per-window ``put_files`` calls.
 
 2. **Expected-launch model** — window hooks accumulate a per-family
    launch *budget* (gear: one per distinct chunker per put window;
@@ -19,9 +20,10 @@ Three checks, mirroring the searslint static passes at runtime:
    cluster; scrub sweeps and metadata-only merges: zero) and
    :meth:`check_launches` asserts the launches
    attributed to this store never exceed it.  Budgets and attributed
-   counts are cumulative over the store's lifetime, so pipelined window
-   interleaving (begin i+1 before finish i) needs no special casing.  The model is an
-   upper bound: an engine may merge buckets, never dispatch more.  Every
+   counts are cumulative over the store's lifetime, so the scheduler's
+   begin-ahead put windows (begin i+1 before finish i) need no special
+   casing.  The model is an upper bound: an engine may merge buckets,
+   never dispatch more.  Every
    chunk of a put window is hashed on the device (the engine's SHA-1
    cap covers the store's largest ``chunk_max``), so the sha1 budget
    counts all of them.
@@ -157,7 +159,7 @@ class Sanitizer:
         if cache is not None:
             # resident set, LRU order and the write-back queue are all
             # control-plane state: a begin seam that touches the cache
-            # would break pipelined/sequential equivalence exactly like
+            # would break begin-ahead/sequential equivalence exactly like
             # an index mutation (cache reads in _plan_get happen outside
             # the guarded begins, so legitimate traffic never trips this)
             for key, data, dirty in cache.entries():
@@ -175,8 +177,8 @@ class Sanitizer:
             raise SanitizerError(
                 f"begin-phase `{label}` mutated control-plane state "
                 "(index/meta/cluster/binding/repair); begin seams must "
-                "be pure for pipelined windows to stay byte-identical "
-                "to sequential")
+                "be pure for begin-ahead put windows to stay "
+                "byte-identical to sequential")
         return out
 
     # ----------------------------------------------- expected-launch model --

@@ -354,6 +354,14 @@ class BatchScheduler:
     ``SchedulerStats.n_shard_subwindows`` counts them, and the bucket
     bound above holds *per shard sub-window*.
 
+    **Double-buffered puts**: a flush issues the next put window's
+    device chunking pass (``_put_window_begin``) before the current put
+    window's host phases run, so the gear launch of window *i+1*
+    overlaps the dedup planning and piece writes of window *i*;
+    ``SchedulerStats.n_pipelined_windows`` counts the windows issued
+    ahead.  Begin touches no store state, so every window stays
+    byte-identical to sequential per-window ``put_files`` calls.
+
     **Auto-flush**: with ``flush_bytes`` set, a submit that lifts the
     pending put payload to/over the threshold flushes the whole queue
     immediately; with ``flush_interval`` set, a submit arriving more than
@@ -392,7 +400,6 @@ class BatchScheduler:
                  repair_chunks_per_flush: int | None = None,
                  scrub_interval: float | None = None,
                  scrub_budget=None,
-                 pipeline: bool = True,
                  lanes: bool = False,
                  max_pending: int | None = None,
                  max_queue_bytes: int | None = None,
@@ -427,12 +434,6 @@ class BatchScheduler:
         # write-back lane: bytes of dirty chunk data drained from the
         # store's block cache per flush window (None = drain fully)
         self.writeback_bytes_per_flush = writeback_bytes_per_flush
-        # double-buffer put windows within a flush: issue window i+1's
-        # device chunking pass before window i's host phases run.  The
-        # begin phase touches no store state, so results stay
-        # byte-identical to pipeline=False (sequential-equivalence tests
-        # cover both settings).
-        self.pipeline = pipeline
 
     # ------------------------------------------------------------- submit --
     def submit_put(self, user: str, files: list[tuple[str, bytes]],
@@ -641,11 +642,10 @@ class BatchScheduler:
     def _run_windows(self, requests: list[Request]) -> None:
         """The foreground put/get/delete windows of one flush."""
         windows = self._windows(requests)
-        # pipelined put ingest: PutWindowState for put windows whose
-        # chunk pass was issued ahead of their execution slot.  Beginning
-        # a put window reads no store state, so issuing it early -- even
-        # across an intervening get/delete window -- cannot change any
-        # window's outcome.
+        # ``begun`` holds the PutWindowState of put windows whose chunk
+        # pass was issued ahead of their slot.  Beginning a put window
+        # touches no store state, so issuing it early -- even across an
+        # intervening get/delete window -- changes no window's outcome.
         begun: dict[int, object] = {}
         # per-shard sub-window accounting: a put/get window on a sharded
         # store demuxes its data-plane batches by owning user shard, and
@@ -659,13 +659,12 @@ class BatchScheduler:
                     state = begun.pop(j, None)
                     if state is None:
                         state = self.store._put_window_begin(window)
-                    if self.pipeline:
-                        for j2 in range(j + 1, len(windows)):
-                            if windows[j2][0].kind == PUT:
-                                begun[j2] = self.store._put_window_begin(
-                                    windows[j2])
-                                self.stats.n_pipelined_windows += 1
-                                break
+                    for j2 in range(j + 1, len(windows)):
+                        if windows[j2][0].kind == PUT:
+                            begun[j2] = self.store._put_window_begin(
+                                windows[j2])
+                            self.stats.n_pipelined_windows += 1
+                            break
                     self.store._put_window_finish(state)
                     self.stats.n_put_windows += 1
                     if demux is not None:

@@ -417,8 +417,12 @@ class SEARSStore:
         SHA-1 launch and one GF(256) launch per (code, length) bucket per
         flush window across *all* queued users) while staying
         byte-identical to sequential per-user ``put_files``/``get_files``
-        calls.  Submits return :class:`repro.core.scheduler.RequestFuture`
-        handles that resolve at ``flush()``/``poll()``.
+        calls.  This is the store's one windowed path for streams of
+        requests: put windows run through ``_put_window_begin``/
+        ``_put_window_finish`` with the next put window's chunking
+        issued ahead, get windows through ``_batch_get``.  Submits
+        return :class:`repro.core.scheduler.RequestFuture` handles that
+        resolve at ``flush()``/``poll()``.
         """
         from repro.core.scheduler import BatchScheduler
         return BatchScheduler(self, queue=queue, **kwargs)
@@ -468,51 +472,10 @@ class SEARSStore:
         objects; this method raises nothing per-request.
 
         Implemented as ``_put_window_begin`` + ``_put_window_finish`` so
-        callers that hold several windows (``put_windows_pipelined``, the
-        scheduler's pipelined flush) can issue window *i+1*'s device
-        chunking pass before window *i*'s host phases complete.
+        the scheduler's flush can issue window *i+1*'s device chunking
+        pass before window *i*'s host phases complete.
         """
         self._put_window_finish(self._put_window_begin(requests))
-
-    def put_windows_pipelined(self, windows, timestamp: float = 0.0,
-                              storage_class: str | None = None
-                              ) -> list[list[UploadStats]]:
-        """Upload a stream of put windows with double-buffered ingest.
-
-        ``windows`` is an iterable (list or generator, e.g.
-        ``repro.core.workload.streaming_window_trace``) of window batches,
-        each ``[(user, [(filename, data), ...]), ...]``.  Window *i+1*'s
-        device chunking pass is issued before window *i*'s host phases
-        (boundary selection, dedup planning, piece writes) run, so on the
-        kernel engines the gear launch of the next window overlaps the
-        control-plane work of the current one.  Results are byte- and
-        stats-identical to sequential ``put_files`` calls per window
-        batch (begin touches no store state; all dedup/placement happens
-        at finish time in window order).  Returns one flat
-        ``[UploadStats]`` list per window, in request order; any request
-        failure raises, exactly like ``put_files``.
-        """
-        from repro.core.scheduler import PUT, Request
-        all_reqs: list[list] = []
-        prev: PutWindowState | None = None
-        for batch in windows:
-            reqs = [Request(request_id=i, user=user, kind=PUT,
-                            files=list(files), timestamp=timestamp,
-                            storage_class=storage_class)
-                    for i, (user, files) in enumerate(batch)]
-            all_reqs.append(reqs)
-            state = self._put_window_begin(reqs)
-            if prev is not None:
-                self._put_window_finish(prev)
-            prev = state
-        if prev is not None:
-            self._put_window_finish(prev)
-        out: list[list[UploadStats]] = []
-        for reqs in all_reqs:
-            for req in reqs:
-                self._one_request(req)
-            out.append([s for req in reqs for s in req.result])
-        return out
 
     def _put_window_begin(self, requests) -> "PutWindowState":
         """Validate payloads and *issue* the window's chunking pass.
@@ -1132,104 +1095,6 @@ class SEARSStore:
         self._one_request(req)
         return req.result
 
-    def get_files_pipelined(self, user: str, filenames: list[str],
-                            window_files: int = 4,
-                            local_chunk_ids: set[bytes] | None = None,
-                            rho_fn=None,
-                            storage_class: str | None = None
-                            ) -> list[tuple[bytes, RetrievalStats]]:
-        """Retrieve many files with a prefetched double-buffered pipeline.
-
-        Files are grouped into windows of ``window_files``; while window
-        *i*'s decode launches are in flight on the device, window
-        *i+1*'s control-plane work -- ``RetrievalPlan`` construction and
-        bulk cluster piece reads -- is issued, and only then is window
-        *i* materialized and assembled.  Byte- and stats-identical to
-        ``get_files`` over the same filename list (assembly order, and
-        therefore the latency-model rng draw order, is filename order in
-        both paths); failures raise exactly like ``get_files``.
-        """
-        windows = [filenames[i:i + window_files]
-                   for i in range(0, len(filenames), window_files)]
-        out: list[tuple[bytes, RetrievalStats]] = []
-        prev = None
-        for window in windows:
-            state = self._get_window_begin(user, window, local_chunk_ids,
-                                           storage_class)
-            if prev is not None:
-                out.extend(self._get_window_finish(prev, rho_fn))
-            prev = state
-        if prev is not None:
-            out.extend(self._get_window_finish(prev, rho_fn))
-        return out
-
-    def _get_window_begin(self, user: str, filenames: list[str],
-                          local_chunk_ids: set[bytes] | None,
-                          storage_class: str | None):
-        """Plan + read one retrieval window and *issue* its decodes.
-
-        Raises on a missing file or an unrecoverable chunk (same errors,
-        same messages as ``get_files``); on success returns a state whose
-        decode launches are in flight but unmaterialized.
-        """
-        plans = [self._plan_get(user, fn, local_chunk_ids,
-                                storage_class=storage_class)
-                 for fn in filenames]
-        tasks = [t for p in plans for t in p.fetch_tasks]
-        by_cluster: dict[int, list[FetchTask]] = {}
-        for t in tasks:
-            by_cluster.setdefault(t.cluster_id, []).append(t)
-        for cluster_id, ctasks in by_cluster.items():
-            got = self._read_cluster_pieces(cluster_id,
-                                            [t.chunk_id for t in ctasks])
-            for t in ctasks:
-                t.pieces = got[t.chunk_id]
-        for t in tasks:
-            systematic = set(range(self.clusters[t.cluster_id].k))
-            if t.pieces is not None and set(t.pieces) != systematic:
-                self.repair.hint(t.chunk_id, t.cluster_id)
-        for t in tasks:
-            want = self.clusters[t.cluster_id].k
-            if len(t.pieces) < want:
-                raise ValueError(
-                    f"need >= k={want} pieces to decode, got "
-                    f"{len(t.pieces)} (chunk {t.chunk_id.hex()})")
-        uniq: dict[tuple[bytes, int], FetchTask] = {}
-        for p in plans:
-            for t in p.fetch_tasks:
-                uniq.setdefault((t.chunk_id, t.cluster_id), t)
-        jobs = [(self.clusters[t.cluster_id].code, t.pieces, t.length)
-                for t in uniq.values()]
-        if self._sanitizer is not None:
-            # at most one GF decode launch per unique chunk (bucketing
-            # merges same-(code, length) jobs below this bound); the
-            # engine begin itself must not touch store state
-            self._sanitizer.add_budget(gf=len(jobs))
-            token = self._sanitizer.guard_begin(
-                "decode_blobs_multi_begin",
-                self.engine.decode_blobs_multi_begin, jobs)
-        else:
-            token = self.engine.decode_blobs_multi_begin(jobs)
-        return (plans, list(uniq), token)
-
-    def _get_window_finish(self, state, rho_fn
-                           ) -> list[tuple[bytes, RetrievalStats]]:
-        """Materialize an issued retrieval window and assemble its files."""
-        plans, keys, token = state
-        blobs = self.engine.decode_blobs_multi_finish(token)
-        blob_by_key = dict(zip(keys, blobs))
-        if self.cache is not None:
-            for (cid, cl), blob in blob_by_key.items():
-                self.cache.fill(cid, cl, blob)
-        out = [self._assemble(
-            plan,
-            {t.chunk_id: blob_by_key[(t.chunk_id, t.cluster_id)]
-             for t in plan.fetch_tasks},
-            rho_fn) for plan in plans]
-        if self._sanitizer is not None:
-            self._sanitizer.check_launches("get window")
-        return out
-
     def _batch_get(self, requests) -> None:
         """Shared get window: coalesce many requests' reads and decodes.
 
@@ -1333,9 +1198,8 @@ class SEARSStore:
                     jobs = [(self.clusters[t.cluster_id].code, t.pieces,
                              t.length) for t in uniq.values()]
                     if self._sanitizer is not None:
-                        # same decode model as _get_window_begin, per shard
-                        # sub-window: one GF launch per unique chunk is the
-                        # ceiling, bucketing stays below
+                        # per shard sub-window, one GF launch per unique
+                        # chunk is the ceiling; bucketing stays below
                         self._sanitizer.add_budget(gf=len(jobs))
                         blobs = self._sanitizer.track(
                             self.engine.decode_blobs_multi, jobs)

@@ -175,59 +175,13 @@ def _personal_bytes(seed: int, size: int, pool: _BlockPool,
 
 
 @dataclasses.dataclass(frozen=True)
-class MultiUserConfig:
-    """Trace shape for the cross-user batch scheduler (switching node).
-
-    Many users upload concurrently; a configurable fraction of each
-    user's content comes from a shared block pool, so coalesced windows
-    carry the inter-user redundancy the scheduler's shared dedup/coding
-    batches are built to exploit.
-    """
-
-    n_users: int = 8
-    files_per_user: int = 4
-    file_kb: int = 48
-    shared_fraction: float = 0.4  # of each file drawn from the shared pool
-    block: int = 8 << 10
-    seed: int = 23
-
-
-def multi_user_put_trace(cfg: MultiUserConfig
-                         ) -> list[tuple[str, list[tuple[str, bytes]]]]:
-    """Per-user upload batches: one (user, files) put request each.
-
-    Deterministic in ``cfg.seed``.  Files mix user-private bytes with
-    blocks from a cross-user shared pool, mirroring the paper workload's
-    inter-user redundancy at request granularity.
-    """
-    rng = np.random.default_rng(cfg.seed)
-    pool = _BlockPool(rng, cfg.block, count=256)
-    trace: list[tuple[str, list[tuple[str, bytes]]]] = []
-    for u in range(cfg.n_users):
-        files: list[tuple[str, bytes]] = []
-        for f in range(cfg.files_per_user):
-            blob = _mixed_bytes(cfg.seed * 1_000_003 + u * 997 + f,
-                                cfg.file_kb << 10, pool,
-                                cfg.shared_fraction, cfg.block)
-            files.append((f"u{u}/f{f}", blob))
-        trace.append((f"user{u}", files))
-    return trace
-
-
-def multi_user_get_trace(put_trace: list[tuple[str, list[tuple[str, bytes]]]]
-                         ) -> list[tuple[str, list[str]]]:
-    """Matching retrieval requests: every user re-fetches its own files."""
-    return [(user, [fn for fn, _ in files]) for user, files in put_trace]
-
-
-@dataclasses.dataclass(frozen=True)
 class StreamingConfig:
-    """Trace shape for the double-buffered multi-window ingest pipeline.
+    """Trace shape for a stream of back-to-back put windows.
 
     A steady stream of put windows -- each one flush-window's worth of
-    per-user batches -- arriving back to back, the workload
-    ``SEARSStore.put_windows_pipelined`` overlaps: window *i+1*'s device
-    chunking pass runs under window *i*'s host phases.  A shared block
+    per-user batches -- arriving back to back, the workload the
+    scheduler's double-buffered put windows overlap: window *i+1*'s
+    device chunking pass runs under window *i*'s host phases.  A shared block
     pool spans all windows so later windows dedup against earlier ones
     (cross-window redundancy), exactly like a long-running switching
     node's traffic.
@@ -248,10 +202,8 @@ def streaming_window_trace(cfg: StreamingConfig
     """Lazily yield put windows of (user, files) batches.
 
     Deterministic in ``cfg.seed`` -- every (window, user, file) triple
-    derives its own content seed -- and a generator on purpose: the
-    pipelined ingest path consumes windows as a stream, materializing at
-    most two (the one finishing and the one whose chunk pass is in
-    flight).
+    derives its own content seed -- and a generator, so a caller can
+    consume windows as a stream without materializing the whole trace.
     """
     rng = np.random.default_rng(cfg.seed)
     pool = _BlockPool(rng, cfg.block, count=256)
@@ -630,67 +582,6 @@ def apply_storm(store, events: list[StormEvent]) -> list:
         else:
             raise ValueError(f"unknown storm event kind {ev.kind!r}")
     return reports
-
-
-@dataclasses.dataclass(frozen=True)
-class SLOTraceConfig:
-    """Closed-loop zipf trace for the block-cache / SLO benchmark.
-
-    Models a million-user switching node front end: user identities are
-    drawn zipf-ranked from an ``n_users``-sized id space (a handful of
-    heavy hitters dominate, the long tail appears once), and every
-    operation touches one file of a small shared **hot catalog** whose
-    contents are identical across users -- the canonical
-    popular-object workload (software updates, viral media).  Under a
-    pool-scoped-dedup CLB class each catalog file's chunks are stored
-    exactly once system-wide, so repeated access from *different* users
-    converges on the same chunk copies: precisely the traffic a
-    switching-node block cache exists to absorb.
-
-    The trace is closed-loop: the first time a (user, file) pair
-    appears it is a put, every later appearance is a get -- each user
-    must upload before it can fetch, and the hot files accumulate gets.
-    """
-
-    n_users: int = 1_000_000  # zipf-ranked user-id space
-    n_ops: int = 200
-    catalog_files: int = 32  # shared hot-catalog size
-    file_kb: int = 24
-    zipf_a: float = 1.2  # skew of both the user and the file popularity
-    storage_class: str | None = "archival"  # class the bench replays under
-    seed: int = 83
-
-
-def zipf_slo_trace(cfg: SLOTraceConfig) -> list[tuple]:
-    """Deterministic (put|get, user, payload) ops, multi_shard_trace style.
-
-    * ``("put", user, [(filename, blob)])`` -- first touch of a
-      (user, catalog file) pair
-    * ``("get", user, [filename])`` -- every repeat touch
-
-    Catalog file ``j``'s bytes depend only on ``(seed, j)``, never on
-    the user, so cross-user dedup (and therefore cache-hit sharing) is
-    structural, not accidental.
-    """
-    rng = np.random.default_rng(cfg.seed)
-    catalog = []
-    for j in range(cfg.catalog_files):
-        r = np.random.default_rng(cfg.seed * 5_000_011 + j)
-        catalog.append(r.integers(0, 256, size=cfg.file_kb << 10,
-                                  dtype=np.int64).astype(np.uint8).tobytes())
-    seen: set[tuple[int, int]] = set()
-    ops: list[tuple] = []
-    for _ in range(cfg.n_ops):
-        uid = (int(rng.zipf(cfg.zipf_a)) - 1) % cfg.n_users
-        j = (int(rng.zipf(cfg.zipf_a)) - 1) % cfg.catalog_files
-        user = f"user{uid}"
-        fname = f"u{uid}/c{j}"
-        if (uid, j) not in seen:
-            seen.add((uid, j))
-            ops.append(("put", user, [(fname, catalog[j])]))
-        else:
-            ops.append(("get", user, [fname]))
-    return ops
 
 
 def request_trace(cfg: WorkloadConfig, events: list[FileEvent],

@@ -147,29 +147,6 @@ def rs_decode_blobs(code, jobs: list[tuple[dict[int, bytes], int]],
         quantum=TILE_L, pad_batch=_pow2)
 
 
-def rs_decode_blobs_begin(code, jobs: list[tuple[dict[int, bytes], int]],
-                          impl: str = "kernel"):
-    """Issue the decode launches for a job batch without materializing.
-
-    Same bucketing and launch economics as ``rs_decode_blobs``; the
-    returned state holds unmaterialized device arrays (JAX async
-    dispatch), so the caller can overlap host work -- planning and
-    cluster reads for the *next* retrieval window -- with the decode.
-    Pass the state to ``rs_decode_blobs_finish`` for the bytes.
-    """
-    from repro.core import rs_code
-    from repro.kernels.gf_matmul import TILE_L
-    return rs_code.batch_decode_blobs_begin(
-        code, jobs, lambda M, arr: rs_apply(M, arr, impl=impl),
-        quantum=TILE_L, pad_batch=_pow2)
-
-
-def rs_decode_blobs_finish(state) -> list[bytes]:
-    """Block on launches issued by ``rs_decode_blobs_begin`` -> blobs."""
-    from repro.core import rs_code
-    return rs_code.batch_decode_blobs_finish(state)
-
-
 # ------------------------------------------------------------------ gear ---
 @jax.jit
 def _gear_ref_padded(data: jnp.ndarray) -> jnp.ndarray:

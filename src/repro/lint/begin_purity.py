@@ -1,9 +1,10 @@
 """Pass 1 — begin-purity.
 
-The pipelined put/get seams (PR 6) rely on ``*_begin`` phases being pure
-with respect to control-plane state: window i+1's begin runs while
-window i's finish is still mutating the store, so a begin that touches
-store/cluster/dedup state breaks the byte-identity proof.  This pass
+The scheduler's double-buffered put windows rely on ``*_begin`` phases
+being pure with respect to control-plane state: put window i+1's begin
+runs before window i's finish has mutated the store, so a begin that
+touches store/cluster/dedup state breaks the byte-identity proof against
+sequential per-window ``put_files`` calls.  This pass
 resolves the call graph reachable from every ``*_begin`` function in
 ``engine.py`` / ``chunking.py`` / ``ops.py`` / ``rs_code.py`` and flags:
 

@@ -1,7 +1,7 @@
 """Pass 4 — plan determinism.
 
 Placement, binding and window demux must be replayable: two runs over
-the same trace must produce byte-identical plans, and the pipelined
+the same trace must produce byte-identical plans, and the scheduler
 differential proofs compare exactly that.  Iterating a ``set`` (hash
 order) anywhere a plan is built breaks it silently.  This pass flags,
 in ``store.py`` / ``scheduler.py`` / ``repair.py`` / ``shard.py``:

@@ -1,8 +1,8 @@
 """Differential N-shard-vs-1-shard proof harness.
 
 The sharded control plane's contract is *byte identity*: an N-shard
-store replaying any trace — through any engine, with or without the
-scheduler pipeline, including mid-trace shard add/drain — must be
+store replaying any trace — through any engine, directly or through
+the scheduler's flush windows, including mid-trace shard add/drain — must be
 indistinguishable from the 1-shard store on every observable:
 
 * every byte returned by every get (captured per-op during replay);
@@ -54,16 +54,18 @@ def _apply_lifecycle(store: SEARSStore, op: tuple) -> None:
 
 
 def replay(store: SEARSStore, ops: list[tuple], *,
-           mode: str = "direct", pipeline: bool = False,
+           mode: str = "direct",
            lifecycle: bool = True, flush_every: int = 4,
            with_stats: bool = True) -> list:
     """Run a ``multi_shard_trace`` op list; return the observation log.
 
     ``mode="direct"`` drives the store API per op; ``mode="scheduler"``
-    routes ops through a :class:`BatchScheduler` (optionally with the
-    double-buffered put pipeline), flushing every ``flush_every`` ops and
-    before any lifecycle op, so add/drain always lands between flush
-    windows of the *trace* (the in-window case has its own tests).
+    routes ops through a :class:`BatchScheduler`, flushing every
+    ``flush_every`` ops and before any lifecycle op, so add/drain always
+    lands between flush windows of the *trace* (the in-window case has
+    its own tests).  With ``flush_every=1`` each flush holds one window,
+    so no put window is begun ahead; with more, a flush holding several
+    put windows issues each next one's chunking pass ahead.
     Lifecycle ops are skipped when ``lifecycle`` is false — the 1-shard
     baseline mode.  ``with_stats=False`` logs only the blob digests —
     the cache differential uses it, since hits legitimately change the
@@ -92,7 +94,7 @@ def replay(store: SEARSStore, ops: list[tuple], *,
         return obs
 
     assert mode == "scheduler", mode
-    sched = store.scheduler(pipeline=pipeline)
+    sched = store.scheduler()
     gets: list = []
 
     def _flush() -> None:
@@ -170,15 +172,15 @@ def assert_shard_balance(store: SEARSStore) -> None:
 
 def run_differential(cfg: ShardTraceConfig, *, shards: int,
                      engine: str = "numpy", mode: str = "direct",
-                     pipeline: bool = False) -> tuple[dict, dict]:
+                     flush_every: int = 4) -> tuple[dict, dict]:
     """The reusable proof: same trace, 1 shard vs N shards (with any
     lifecycle ops applied only on the sharded side), byte-identical."""
     ops = multi_shard_trace(cfg)
     base = build_store(engine=engine, shards=1)
-    base_obs = replay(base, ops, mode=mode, pipeline=pipeline,
+    base_obs = replay(base, ops, mode=mode, flush_every=flush_every,
                       lifecycle=False)
     subj = build_store(engine=engine, shards=shards)
-    subj_obs = replay(subj, ops, mode=mode, pipeline=pipeline)
+    subj_obs = replay(subj, ops, mode=mode, flush_every=flush_every)
     assert_identical((base_obs, artifacts(base)),
                      (subj_obs, artifacts(subj)))
     assert_shard_balance(subj)
@@ -187,7 +189,7 @@ def run_differential(cfg: ShardTraceConfig, *, shards: int,
 
 def run_cache_differential(cfg: ShardTraceConfig, *, shards: int = 1,
                            engine: str = "numpy", mode: str = "direct",
-                           pipeline: bool = False,
+                           flush_every: int = 4,
                            write_back: bool = True,
                            capacity_bytes: int = 64 << 20
                            ) -> tuple[dict, dict]:
@@ -205,12 +207,12 @@ def run_cache_differential(cfg: ShardTraceConfig, *, shards: int = 1,
     """
     ops = multi_shard_trace(cfg)
     base = build_store(engine=engine, shards=shards)
-    base_obs = replay(base, ops, mode=mode, pipeline=pipeline,
+    base_obs = replay(base, ops, mode=mode, flush_every=flush_every,
                       with_stats=False)
     subj = build_store(engine=engine, shards=shards,
                        cache=CacheConfig(capacity_bytes=capacity_bytes,
                                          write_back=write_back))
-    subj_obs = replay(subj, ops, mode=mode, pipeline=pipeline,
+    subj_obs = replay(subj, ops, mode=mode, flush_every=flush_every,
                       with_stats=False)
     subj.flush()
     base_art, subj_art = artifacts(base), artifacts(subj)

@@ -145,11 +145,12 @@ def test_writeback_put_defers_upload_until_flush(sanitize):
     s = _store(cache=CacheConfig(write_back=True), sanitize=sanitize)
     blob = _data(150_000, seed=6)
     s.put_file("u", "f", blob)
-    assert s.cache.dirty_count > 0
+    dirty = s.cache.dirty_count
+    assert dirty > 0
     assert sum(c.used for c in s.clusters) == 0  # nothing landed yet
     assert sum(c._reserved for c in s.clusters) > 0  # but space is promised
     drained = s.flush()
-    assert drained > 0 and s.cache.dirty_count == 0
+    assert drained == dirty and s.cache.dirty_count == 0
     assert sum(c._reserved for c in s.clusters) == 0
     assert sum(c.used for c in s.clusters) > 0
     got, _ = s.get_file("u", "f")
@@ -201,7 +202,7 @@ def test_submit_put_then_submit_delete_race_regression():
     cancel the not-yet-drained upload, leaving no reservation, no
     pieces, no index record — the original write-back ordering bug."""
     s = _store(cache=CacheConfig(write_back=True), sanitize=True)
-    sched = BatchScheduler(s, pipeline=False)
+    sched = BatchScheduler(s)
     blob = _data(90_000, seed=9)
     put = sched.submit_put("u", [("f", blob)])
     delete = sched.submit_delete("u", ["f"])
@@ -283,7 +284,7 @@ def test_lanes_run_realtime_before_archival():
                 storage_class="archival")
     s.put_files("r", [("f", _data(40_000, seed=61))],
                 storage_class="realtime")
-    sched = BatchScheduler(s, lanes=True, pipeline=False)
+    sched = BatchScheduler(s, lanes=True)
     arc = sched.submit_get("a", ["f"], storage_class="archival")
     rt = sched.submit_get("r", ["f"], storage_class="realtime")
     drained = sched.flush()
@@ -298,7 +299,7 @@ def test_admission_sheds_lower_priority_newest_first():
                 storage_class="archival")
     s.put_files("r", [("f", _data(30_000, seed=63))],
                 storage_class="realtime")
-    sched = BatchScheduler(s, lanes=True, pipeline=False, max_pending=2)
+    sched = BatchScheduler(s, lanes=True, max_pending=2)
     arc1 = sched.submit_get("a", ["f"], storage_class="archival")
     arc2 = sched.submit_get("a", ["f"], storage_class="archival")
     arc3 = sched.submit_get("a", ["f"], storage_class="archival")
@@ -322,7 +323,7 @@ def test_admission_never_sheds_equal_or_higher_priority():
     s = _two_class_store()
     s.put_files("r", [("f", _data(30_000, seed=64))],
                 storage_class="realtime")
-    sched = BatchScheduler(s, lanes=True, pipeline=False, max_pending=1)
+    sched = BatchScheduler(s, lanes=True, max_pending=1)
     rt1 = sched.submit_get("r", ["f"], storage_class="realtime")
     rt2 = sched.submit_get("r", ["f"], storage_class="realtime")
     assert rt1.request.error is None  # the queued one survives
@@ -333,9 +334,131 @@ def test_admission_never_sheds_equal_or_higher_priority():
     assert rt1.ok
 
 
+# realtime p99 at peak archival load vs its unloaded p99
+SLO_FACTOR = 1.5
+
+
+def _pctl(xs, q):
+    ys = sorted(xs)
+    return ys[min(len(ys) - 1, int(round(q * (len(ys) - 1))))]
+
+
+def _overload_arm(admission: bool) -> dict:
+    """Closed-loop two-class sweep on a fake clock: a fixed realtime flow
+    (3 users) rides a scheduler while archival demand steps from 1 to 48
+    gets a window, four windows a rate.  Each window's admitted get
+    bytes set the rho the next window is charged (1.5 MB is the box's
+    absorbable demand), so shedding archival load is what keeps
+    realtime latency flat."""
+    from repro.core.latency import calibrate
+    s = SEARSStore(classes=[StorageClass.realtime(),
+                            StorageClass.archival()],
+                   num_clusters=8, node_capacity=1 << 30,
+                   latency=calibrate())
+    now = [0.0]
+    sched = BatchScheduler(s, clock=lambda: now[0], lanes=True,
+                           max_pending=8 if admission else None)
+    rt_files = [(f"rt/f{i}", _data(24 << 10, seed=7 + i)) for i in range(3)]
+    arc_files = [(f"arc/f{i}", _data(48 << 10, seed=57 + i))
+                 for i in range(4)]
+    for u in range(3):
+        s.put_files(f"rt{u}", rt_files, storage_class="realtime")
+    for u in range(12):
+        s.put_files(f"arc{u}", arc_files, storage_class="archival")
+    box = {"prev": 0.0}
+
+    def rho_fn(cluster_id):
+        return min(0.95, box["prev"] / 1.5e6)
+
+    out = {"p99": {}, "offered": {}, "done": {}, "rejected": {},
+           "failed": {}}
+    for key in ("offered", "done", "rejected", "failed"):
+        out[key] = {"realtime": 0, "archival": 0}
+    for rate in (1, 48):
+        rt_times = []
+        for w in range(4):
+            # archival flood first: the lanes must reorder, and realtime
+            # submits shed queued archival
+            futs = [("archival", sched.submit_get(
+                f"arc{(w * rate + j) % 12}", [arc_files[(w + j) % 4][0]],
+                rho_fn=rho_fn, storage_class="archival"))
+                for j in range(rate)]
+            futs += [("realtime", sched.submit_get(
+                f"rt{u}", [rt_files[w % 3][0]], rho_fn=rho_fn,
+                storage_class="realtime")) for u in range(3)]
+            sched.flush()
+            admitted = 0
+            for klass, fut in futs:
+                out["offered"][klass] += 1
+                if fut.error is None and fut.ok:
+                    out["done"][klass] += 1
+                    for _, st in fut.request.result:
+                        admitted += st.file_bytes
+                        if klass == "realtime":
+                            rt_times.append(st.time_s)
+                elif isinstance(fut.error, AdmissionError):
+                    out["rejected"][klass] += 1
+                else:
+                    out["failed"][klass] += 1
+            box["prev"] = admitted
+            now[0] += 1.0
+        out["p99"][rate] = _pctl(rt_times, 0.99)
+        box["prev"] = 0.0  # cool the box between rates
+    out["shed"] = sched.stats.n_admission_shed
+    return out
+
+
+def test_admission_control_keeps_realtime_p99_within_slo_at_peak():
+    on, off = _overload_arm(True), _overload_arm(False)
+    for arm in (on, off):
+        for klass in ("realtime", "archival"):
+            assert arm["offered"][klass] == (arm["done"][klass]
+                                             + arm["rejected"][klass]
+                                             + arm["failed"][klass])
+    assert on["p99"][48] <= SLO_FACTOR * on["p99"][1]
+    # the peak reached the knee: archival was shed or rejected, and
+    # realtime never was while archival could give way
+    assert on["rejected"]["archival"] or on["shed"]
+    assert on["rejected"]["realtime"] == 0
+    # the control is load-bearing: without it realtime drowns
+    assert off["p99"][48] > SLO_FACTOR * off["p99"][1]
+
+
+def test_cache_hit_p50_beats_cold_p50_by_5x():
+    """A hot catalog read by several users from an archival (CLB) store:
+    full cache hits stream from the switching node and skip the
+    cross-cluster search, so their modelled p50 is >= 5x faster than
+    the cache-less store's cold p50."""
+    from repro.core.latency import calibrate
+    catalog = [(f"c{j}", _data(24 << 10, seed=500 + j)) for j in range(6)]
+    names = [fn for fn, _ in catalog]
+
+    def replay(cache):
+        s = SEARSStore(classes=[StorageClass.archival()], num_clusters=6,
+                       node_capacity=1 << 30, latency=calibrate(),
+                       cache=cache)
+        for u in range(4):
+            s.put_files(f"user{u}", catalog)
+        cold, hit = [], []
+        for _ in range(3):
+            for u in range(4):
+                for _, st in s.get_files(f"user{u}", names):
+                    if st.n_cache_hits == st.n_chunks:
+                        hit.append(st.time_s)
+                    elif st.n_cache_hits == 0:
+                        cold.append(st.time_s)
+        return cold, hit
+
+    cold, none_hit = replay(False)
+    assert not none_hit
+    _, hit = replay(CacheConfig(capacity_bytes=32 << 20))
+    assert hit, "the cache never engaged"
+    assert _pctl(cold, 0.50) >= 5.0 * _pctl(hit, 0.50)
+
+
 def test_scheduler_writeback_lane_drains_in_flush_windows():
     s = _store(cache=CacheConfig(write_back=True))
-    sched = BatchScheduler(s, pipeline=False)
+    sched = BatchScheduler(s)
     put = sched.submit_put("u", [("f", _data(80_000, seed=65))])
     sched.flush()
     assert put.ok
@@ -348,7 +471,7 @@ def test_scheduler_writeback_lane_drains_in_flush_windows():
 
 def test_scheduler_writeback_lane_respects_per_flush_budget():
     s = _store(cache=CacheConfig(write_back=True))
-    sched = BatchScheduler(s, pipeline=False, writeback_bytes_per_flush=1)
+    sched = BatchScheduler(s, writeback_bytes_per_flush=1)
     for i in range(3):
         sched.submit_put("u", [(f"f{i}", _data(60_000, seed=70 + i))])
     sched.flush()
@@ -375,11 +498,11 @@ def test_cache_differential_direct(engine, shards):
 
 
 @pytest.mark.parametrize("engine", ["numpy", "kernel", "fused"])
-@pytest.mark.parametrize("pipeline", [False, True])
-def test_cache_differential_scheduler(engine, pipeline):
+@pytest.mark.parametrize("flush_every", [1, 4])
+def test_cache_differential_scheduler(engine, flush_every):
     run_cache_differential(ShardTraceConfig(**LIFE), shards=2,
                            engine=engine, mode="scheduler",
-                           pipeline=pipeline)
+                           flush_every=flush_every)
 
 
 def test_cache_differential_read_only_cache():

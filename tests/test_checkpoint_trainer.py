@@ -49,6 +49,20 @@ def test_checkpoint_dedup_across_steps():
     assert s2["dedup_saving"] == 1.0
 
 
+def test_checkpoint_dedup_across_experiments():
+    """A second run that shares a frozen leaf with the first stores that
+    leaf's bytes once: only its own leaves upload."""
+    mgr = SEARSCheckpointManager(node_capacity=1 << 26, run="a")
+    tree = _tree()
+    mgr.save(1, tree)
+    mgr2 = SEARSCheckpointManager(store=mgr.store, run="b")
+    tree2 = _tree(seed=1)
+    tree2["emb"] = tree["emb"]  # shared frozen embedding
+    s = mgr2.save(1, tree2)
+    assert s["dedup_saving"] >= 0.1
+    assert s["bytes_after_dedup"] <= s["bytes"] - tree["emb"].nbytes
+
+
 def test_checkpoint_partial_change_partial_dedup():
     mgr = SEARSCheckpointManager(node_capacity=1 << 26)
     tree = _tree()

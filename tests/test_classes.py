@@ -406,6 +406,28 @@ def test_single_class_store_stats_has_one_slice():
     assert d.index_bytes == stats.index_bytes
 
 
+def test_realtime_wins_retrieval_and_archival_wins_overhead():
+    """The paper's per-class trade-off on a mixed trace: the real-time
+    class (ULB, (10,5)) reads back faster on modelled retrieval time, the
+    archival class (CLB, (14,10), global dedup) stores fewer physical
+    bytes per logical byte."""
+    from repro.core.latency import calibrate
+    s = _mixed_store(node_capacity=1 << 30, latency=calibrate())
+    trace = mixed_class_trace(MixedClassConfig(
+        n_users=3, hot_files_per_user=3, cold_files_per_user=2))
+    for user, files, cls in trace:
+        s.put_files(user, files, storage_class=cls)
+    times: dict[str, list[float]] = {}
+    for user, files, cls in trace:
+        for _, st in s.get_files(user, [fn for fn, _ in files]):
+            times.setdefault(cls, []).append(st.time_s)
+    assert np.mean(times["realtime"]) < np.mean(times["archival"])
+    pc = s.stats().per_class
+    rt, ar = pc["realtime"], pc["archival"]
+    assert (ar.piece_bytes / ar.logical_bytes
+            < rt.piece_bytes / rt.logical_bytes)
+
+
 # ----------------------------------------------------------------- repair --
 @pytest.mark.parametrize("engine", ENGINES)
 def test_storm_repair_rebuilds_both_classes(engine):

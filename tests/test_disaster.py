@@ -30,7 +30,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.cluster import Cluster, NodeDownError
-from repro.core.latency import RepairBandwidth
+from repro.core.latency import RepairBandwidth, calibrate
 from repro.core.repair import RepairManager
 from repro.core.store import SEARSStore
 from repro.core.workload import (StormConfig, apply_storm,
@@ -312,6 +312,63 @@ def test_bandwidth_validates_and_rho_is_capped():
     bw.note(0, 10_000_000)
     assert bw.rho(0) == 0.95  # congestion floor capped below 1.0
     assert bw.rho(1) == 0.0
+
+
+# Foreground p99 budget while a lost cluster rebuilds, x the no-repair p99.
+SLO_FACTOR = 1.5
+
+
+def _pctl(xs, q):
+    ys = sorted(xs)
+    return ys[min(len(ys) - 1, int(round(q * (len(ys) - 1))))]
+
+
+def _foreground_p99(arm: str) -> float:
+    """Modelled foreground get p99 under one repair arm (fake clock).
+
+    Four users store the same four files, so each user's copy on its
+    own ULB cluster is a donor for the others.  ``unthrottled`` and
+    ``throttled`` declare user0's cluster lost and rebuild it; users 1-3
+    read their copies for twelve one-second windows.  The link and the
+    throttle are sized to this data set (a few hundred KB per cluster
+    copy), so an unthrottled rebuild saturates its links inside one
+    window while the throttled one (10% of the link) spreads it out.
+    """
+    now = [0.0]
+    bw = RepairBandwidth(link_bps=200e3,
+                         limit_bps=20e3 if arm == "throttled" else None,
+                         window_s=1.0, clock=lambda: now[0])
+    s = _store(num_clusters=6, node_capacity=1 << 30, latency=calibrate(),
+               repair_bandwidth=bw)
+    files = [(f"f{i}", _data(48 * 1024 + 512 * i, seed=31 + i))
+             for i in range(4)]
+    for u in range(4):
+        s.put_files(f"user{u}", files)
+    if arm != "no_repair":
+        s.declare_cluster_lost(s.binding._bound["user0"])
+        s.repair.repair()  # the throttled arm defers most of the queue
+    names = [fn for fn, _ in files]
+    times = []
+    for _ in range(12):
+        for user in ("user1", "user2", "user3"):
+            times.extend(st.time_s for _, st in s.get_files(user, names))
+        now[0] += 1.0  # next window: the bucket refills, traffic ages
+        if arm == "throttled" and s.repair.pending:
+            s.repair.drain()
+    while s.repair.pending:  # the throttled rebuild still finishes
+        now[0] += 1.0
+        s.repair.drain()
+    for fn, blob in files:
+        assert s.get_file("user0", fn)[0] == blob
+    return _pctl(times, 0.99)
+
+
+def test_throttled_rebuild_keeps_foreground_p99_within_slo():
+    base = _foreground_p99("no_repair")
+    assert _foreground_p99("throttled") <= SLO_FACTOR * base
+    # the throttle is load-bearing: the same rebuild in one burst floors
+    # rho at its congestion cap on every cluster it touched
+    assert _foreground_p99("unthrottled") > SLO_FACTOR * base
 
 
 # -------------------------------------------------------- scrub lane ------

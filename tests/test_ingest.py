@@ -267,7 +267,7 @@ def test_fused_store_matches_numpy_store():
     assert sf.stats() == sn.stats()
 
 
-# --------------------------------------------- double-buffered pipeline ----
+# ------------------------------------------- double-buffered put windows ----
 def _stream_windows(n_windows=3, seed=80):
     from repro.core.workload import StreamingConfig, streaming_window_trace
     cfg = StreamingConfig(n_windows=n_windows, users_per_window=2,
@@ -275,22 +275,43 @@ def _stream_windows(n_windows=3, seed=80):
     return list(streaming_window_trace(cfg))
 
 
+def _stream_store(engine, seed):
+    return SEARSStore(n=10, k=5, num_clusters=4, node_capacity=64 << 20,
+                      binding="ulb", seed=seed, engine=engine)
+
+
 @pytest.mark.parametrize("engine", ["numpy", "kernel", "fused"])
-def test_put_windows_pipelined_matches_sequential(engine):
-    """Double-buffered window ingest commits the same bytes, stats and
-    placement as sequential per-window put_files calls."""
+def test_scheduler_put_windows_match_sequential(engine):
+    """One flush of several put windows (a get between each pair keeps
+    them apart), each next window's chunk pass begun ahead, commits the
+    same bytes, stats and placement as per-window put_files calls."""
     windows = _stream_windows()
 
-    pipe = SEARSStore(n=10, k=5, num_clusters=4, node_capacity=64 << 20,
-                      binding="ulb", seed=7, engine=engine)
-    got = pipe.put_windows_pipelined(windows)
+    pipe = _stream_store(engine, seed=7)
+    sched = pipe.scheduler()
+    put_futs, get_futs = [], []
+    for w, batch in enumerate(windows):
+        if w:
+            user, files = windows[w - 1][0]
+            get_futs.append(sched.submit_get(user,
+                                             [fn for fn, _ in files]))
+        put_futs.append([sched.submit_put(u, fs) for u, fs in batch])
+    sched.flush()
+    assert sched.stats.n_put_windows == len(windows)
+    assert sched.stats.n_pipelined_windows == len(windows) - 1
 
-    seq = SEARSStore(n=10, k=5, num_clusters=4, node_capacity=64 << 20,
-                     binding="ulb", seed=7, engine=engine)
-    want = [[st for user, files in w for st in seq.put_files(user, files)]
-            for w in windows]
+    seq = _stream_store(engine, seed=7)
+    want_puts, want_gets = [], []
+    for w, batch in enumerate(windows):
+        if w:
+            user, files = windows[w - 1][0]
+            want_gets.append(seq.get_files(user, [fn for fn, _ in files]))
+        want_puts.append([st for u, fs in batch
+                          for st in seq.put_files(u, fs)])
 
-    assert got == want
+    assert [[st for f in futs for st in f.result()]
+            for futs in put_futs] == want_puts
+    assert [f.result() for f in get_futs] == want_gets
     assert pipe.stats() == seq.stats()
     for cp, cs in zip(pipe.clusters, seq.clusters):
         for np_, ns in zip(cp.nodes, cs.nodes):
@@ -299,50 +320,64 @@ def test_put_windows_pipelined_matches_sequential(engine):
 
 @pytest.mark.parametrize("engine", ["kernel", "fused"])
 @pytest.mark.parametrize("degraded", [False, True])
-def test_get_files_pipelined_matches_get_files(engine, degraded):
-    """Prefetched multi-window retrieval returns the same bytes and the
-    same latency-model stats as one get_files call (healthy and
-    degraded: systematic memcpy vs real GF decode launches)."""
+def test_scheduler_get_window_matches_get_files(engine, degraded):
+    """One scheduler flush of several users' gets returns the same bytes
+    and the same latency-model stats as per-file get_files calls
+    (healthy and degraded: systematic memcpy vs real GF decode
+    launches)."""
     windows = _stream_windows(seed=81)
-    store = SEARSStore(n=10, k=5, num_clusters=4, node_capacity=64 << 20,
-                       binding="ulb", seed=9, engine=engine)
-    store.put_windows_pipelined(windows)
+    store = _stream_store(engine, seed=9)
+    for batch in windows:
+        for user, files in batch:
+            store.put_files(user, files)
     if degraded:
         for c in store.clusters:
             c.kill_nodes([0, 2, 4, 6, 8])
-    names = [fn for w in windows for u, fs in w if u == "user0"
-             for fn, _ in fs]
+    users = sorted({u for batch in windows for u, _ in batch})
+    names = {u: [fn for batch in windows for v, fs in batch if v == u
+                 for fn, _ in fs] for u in users}
 
     store.rng = np.random.default_rng(123)
-    want = store.get_files("user0", names)
+    want = [store.get_files(u, [fn])[0] for u in users for fn in names[u]]
     store.rng = np.random.default_rng(123)  # same latency rng draws
-    got = store.get_files_pipelined("user0", names, window_files=2)
+    sched = store.scheduler()
+    futs = [sched.submit_get(u, names[u]) for u in users]
+    sched.flush()
+    assert sched.stats.n_get_windows == 1
+    got = [r for f in futs for r in f.result()]
     assert [g[0] for g in got] == [w[0] for w in want]
     assert [g[1] for g in got] == [w[1] for w in want]
 
 
 def test_scheduler_pipelined_flush_matches_unpipelined():
-    """pipeline=True flush: identical artifacts, and the put windows'
-    chunk passes were issued ahead (n_pipelined_windows counts them)."""
+    """A flush whose second put window is begun ahead of the first's
+    host phases (n_pipelined_windows counts it) commits the same
+    artifacts as per-window put_files calls."""
     filesA = [(f"a{i}", _data(15_000, seed=90 + i)) for i in range(3)]
     filesB = [(f"b{i}", _data(14_000, seed=95 + i)) for i in range(3)]
 
-    def run(pipeline):
-        s = SEARSStore(n=10, k=5, num_clusters=4, node_capacity=64 << 20,
-                       binding="ulb", seed=11, engine="fused")
-        sched = s.scheduler(pipeline=pipeline)
-        fa = sched.submit_put("alice", filesA)
-        fg = sched.submit_get("alice", [fn for fn, _ in filesA[:1]])
-        fb = sched.submit_put("bob", filesB)
-        sched.flush()
-        return (fa.result(), fg.result(), fb.result(), s.stats(),
-                sched.stats)
+    def fresh():
+        return SEARSStore(n=10, k=5, num_clusters=4, node_capacity=64 << 20,
+                          binding="ulb", seed=11, engine="fused")
 
-    ra, ga, rb, stats, sst = run(True)
-    ra2, ga2, rb2, stats2, sst2 = run(False)
-    assert (ra, ga, rb, stats) == (ra2, ga2, rb2, stats2)
+    s = fresh()
+    sched = s.scheduler()
+    fa = sched.submit_put("alice", filesA)
+    fg = sched.submit_get("alice", [fn for fn, _ in filesA[:1]])
+    fb = sched.submit_put("bob", filesB)
+    sched.flush()
+    sst = sched.stats
+
+    seq = fresh()
+    ra = seq.put_files("alice", filesA)
+    ga = seq.get_files("alice", [fn for fn, _ in filesA[:1]])
+    rb = seq.put_files("bob", filesB)
+    assert (fa.result(), fg.result(), fb.result(), s.stats()) == \
+        (ra, ga, rb, seq.stats())
+    for cp, cs in zip(s.clusters, seq.clusters):
+        for np_, ns in zip(cp.nodes, cs.nodes):
+            assert np_._pieces == ns._pieces
     assert sst.n_pipelined_windows >= 1
-    assert sst2.n_pipelined_windows == 0
     # the fused engine's ingest launches land in the scheduler's counters
     assert sst.fused_launches >= 1 and sst.sha1_launches == 0
 

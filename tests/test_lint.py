@@ -39,7 +39,7 @@ class Eng:
     def _stash(self, jobs):
         self.table.append(jobs)
 
-    def decode_blobs_multi_begin(self, jobs):
+    def chunk_blobs_multi_begin(self, jobs):
         self._stash(jobs)
         return jobs
 """})

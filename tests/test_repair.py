@@ -374,6 +374,44 @@ def test_repair_sub_batch_launches_scale_with_windows_not_chunks():
     assert delta.gf <= 16 * report.n_sub_batches
 
 
+@pytest.mark.parametrize("engine", ["numpy", "kernel"])
+def test_repair_artifacts_do_not_depend_on_sub_batch_size(engine):
+    """One storm rebuilt chunk by chunk (sub_batch=1) and in batched
+    cross-cluster sub-batches leaves byte-identical nodes, the same
+    balanced ledger and every file readable; on the device engine the
+    per-chunk pass pays at least one GF launch per chunk, the batched
+    one at most 16 per sub-batch."""
+    from repro.kernels.launches import LAUNCHES
+
+    storm = failure_storm_trace(StormConfig(
+        n_clusters=6, n_steps=2, storm_clusters=6, kills_per_storm=2,
+        revive_prob=1.0, replace_fraction=1.0, repair_every_step=False,
+        seed=17))
+    runs = []
+    for sub_batch in (1, 64):
+        s = _store(engine=engine, num_clusters=6)
+        files = _populate(s, n_users=4, files_per_user=3, size=30_000)
+        apply_storm(s, storm)
+        before = LAUNCHES.snapshot()
+        report = RepairManager(s, sub_batch=sub_batch).repair()
+        runs.append((s, report, LAUNCHES.delta(before).gf))
+    (s1, per_chunk, gf1), (s64, batched, gf64) = runs
+    assert per_chunk.balanced and batched.balanced
+    assert not per_chunk.unrecoverable and not batched.unrecoverable
+    assert per_chunk.pieces_rebuilt == batched.pieces_rebuilt > 0
+    for c1, c64 in zip(s1.clusters, s64.clusters):
+        for n1, n64 in zip(c1.nodes, c64.nodes):
+            assert n1._pieces == n64._pieces
+    for user, fs in files.items():
+        for store in (s1, s64):
+            for (fn, blob), (out, _) in zip(
+                    fs, store.get_files(user, [fn for fn, _ in fs])):
+                assert out == blob, f"{user}/{fn} corrupted"
+    if engine == "kernel":
+        assert gf1 >= len(per_chunk.rebuilt)
+        assert gf64 <= 16 * batched.n_sub_batches
+
+
 # ------------------------------------------- storm differential harness ----
 def _storm_roundtrip(engine: str, seed: int) -> None:
     """Safe storm: every file must read back byte-identical afterwards."""

@@ -88,7 +88,13 @@ def test_sanitized_pipelined_windows_match_sequential(engine):
         seq.put_files("u", fs)
 
     pipe = _store(engine=engine)
-    pipe.put_windows_pipelined(wins)
+    sched = pipe.scheduler()
+    for i, (_, fs) in enumerate(wins[0] + wins[1]):
+        if i:  # a get between the puts keeps them two put windows
+            sched.submit_get("u", [files[0][0]])
+        sched.submit_put("u", fs)
+    assert all(r.ok for r in sched.flush())
+    assert sched.stats.n_pipelined_windows == 1
 
     for fn, blob in files:
         out, _ = pipe.get_file("u", fn)
@@ -99,7 +105,7 @@ def test_sanitized_pipelined_windows_match_sequential(engine):
 
 def test_sanitized_scheduler_pipeline_flush():
     s = _store()
-    sched = s.scheduler(pipeline=True)
+    sched = s.scheduler()
     reqs = [sched.submit_put(u, _files(n_files=2, seed=i))
             for i, u in enumerate(("alice", "bob", "carol"))]
     sched.flush()

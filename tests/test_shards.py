@@ -104,10 +104,12 @@ def test_differential_direct(engine, shards):
 
 
 @pytest.mark.parametrize("engine", ["numpy", "kernel", "fused"])
-@pytest.mark.parametrize("pipeline", [False, True])
-def test_differential_scheduler(engine, pipeline):
+@pytest.mark.parametrize("flush_every", [1, 4])
+def test_differential_scheduler(engine, flush_every):
+    # flush_every=1: one window per flush, no put window begun ahead;
+    # 4: flushes carry several put windows, each next one begun ahead
     run_differential(ShardTraceConfig(**LIFE), shards=4, engine=engine,
-                     mode="scheduler", pipeline=pipeline)
+                     mode="scheduler", flush_every=flush_every)
 
 
 def test_single_shard_degenerate_matches_legacy_default():
