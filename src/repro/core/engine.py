@@ -230,13 +230,15 @@ class KernelEngine(CodingEngine):
     body in Python per grid cell and is orders of magnitude slower than
     the XLA-compiled oracle on CPU.
 
-    SHA-1 launches use a fixed batch of ``hash_batch`` messages padded to
-    at most ``max_hash_len`` bytes of message schedule, so the compiled
-    (B, M, 16) shapes stay bounded regardless of workload.  Every chunk is
-    hashed on the device: a chunk longer than ``max_hash_len`` raises
-    ``ValueError``, so the store sizes the cap to its classes' largest
-    ``chunk_max``.  Only a custom ``hash_fn`` (which has no kernel twin)
-    hashes on the host.
+    SHA-1 launches take up to ``hash_batch`` messages with at most
+    ``max_hash_len`` bytes each, so the compiled (lanes, blocks) shapes
+    stay bounded regardless of workload.  The host ships each batch's
+    chunk bytes once, joined flat with their offsets and lengths
+    (``hashing.sha1_pack``); the SHA-1 padding happens on the device, in
+    the same launch as the kernel.  Every chunk is hashed on the device:
+    a chunk longer than ``max_hash_len`` raises ``ValueError``, so the
+    store sizes the cap to its classes' largest ``chunk_max``.  Only a
+    custom ``hash_fn`` (which has no kernel twin) hashes on the host.
     """
 
     name = "kernel"
@@ -297,17 +299,14 @@ class KernelEngine(CodingEngine):
                 # longer drags hash_batch-wide dead lanes through the
                 # compression loop, and the compiled-shape set stays
                 # bounded ({1, 2, 4, ..., hash_batch} x bucketed block
-                # widths)
-                target = min(1 << max(0, len(group) - 1).bit_length(),
-                             self.hash_batch)
-                pad = target - len(group)
+                # widths; the flat buffer's size follows those two)
+                lanes = min(1 << max(0, len(group) - 1).bit_length(),
+                            self.hash_batch)
                 # raises ValueError for a chunk over max_hash_len: growing
                 # the compiled block axis, or hashing it on the host,
                 # would hide it
-                blocks, counts = hashing.sha1_pad_batch(
-                    group + [b""] * pad, max_len=self.max_hash_len)
-            words = to_host(ops.sha1_digest_words(blocks, counts,
-                                                  impl=self.impl))
+                packed = hashing.sha1_pack(group, lanes, self.max_hash_len)
+            words = to_host(ops.sha1_digest_flat(*packed, impl=self.impl))
             with span("sears.engine.unpack"):
                 out += hashing.digest_words_to_bytes(words[:len(group)])
         return out

@@ -1,11 +1,12 @@
 """Chunk identifiers.
 
 Default (paper-faithful): 160-bit SHA-1.  The host storage path uses
-``hashlib`` (exact, C-speed); the device path -- used when chunks already
-live in device memory, e.g. checkpoint shards -- is the batched SHA-1
-Pallas kernel in ``repro.kernels.sha1`` validated against ``hashlib``.
-This module holds the shared message-schedule preprocessing plus a fast
-non-cryptographic 128-bit id for trusted deployments.
+``hashlib`` (exact, C-speed); the device path is the batched SHA-1 Pallas
+kernel in ``repro.kernels.sha1``, validated against ``hashlib``.  This
+module holds the host side of both device layouts -- a batch's bytes laid
+out flat for the padding done on the device (:func:`sha1_pack`), and the
+host-padded message schedule of the fused ingest (:func:`sha1_pad_batch`)
+-- plus a fast non-cryptographic 128-bit id for trusted deployments.
 """
 
 from __future__ import annotations
@@ -40,10 +41,84 @@ def sha1_pad_blocks(data: bytes) -> np.ndarray:
     return words.reshape(-1, 16)
 
 
+def sha1_blocks(max_len: int) -> int:
+    """SHA-1 blocks of a padded message of ``max_len`` bytes."""
+    return (max_len + 9 + 63) // 64
+
+
+def _block_cap(longest: int, max_len: int) -> int:
+    """A launch's block axis: the power of two of the batch's own need,
+    clamped to ``blocks(max_len)``; a longer chunk raises ``ValueError``."""
+    need, fixed = sha1_blocks(longest), sha1_blocks(max_len)
+    if need > fixed:
+        raise ValueError(
+            f"chunk needs {need} SHA-1 blocks > fixed cap {fixed} "
+            f"(max_len={max_len}); raise the caller's max_len")
+    return min(1 << (need - 1).bit_length(), fixed)
+
+
+# A flat SHA-1 buffer is rows of 512 bytes (128 uint32 words: one row of
+# TPU lanes), and each message starts on a row: a lane's window is then a
+# gather of whole rows, with no shift.
+FLAT_ROW = 512
+_ZERO_ROW = bytes(FLAT_ROW)
+
+
+def sha1_flat_rows(lanes: int, n_blocks: int) -> tuple[int, int]:
+    """(first part, whole) rows of a flat SHA-1 buffer.
+
+    The whole buffer holds every lane at its longest (a message of at
+    most ``n_blocks * 64 - 9`` bytes spans at most ``ceil(n_blocks / 8)``
+    rows, the window each lane reads), so no window runs off the end.
+    The first part holds 40 bytes per lane and block, 5/8 of the worst
+    case: a CDC chunker's mean chunk sits near half its maximum (the gear
+    chunker's at 0.54), so a batch of a few hundred chunks stays inside
+    it and only a rarer batch ships the second part too.  Both sizes
+    follow ``(lanes, n_blocks)`` alone: a launch's shape never follows
+    its batch's byte count.
+    """
+    return -(-5 * lanes * n_blocks // 64), lanes * -(-n_blocks // 8)
+
+
+def sha1_pack(chunks: list[bytes], lanes: int, max_len: int
+              ) -> tuple[np.ndarray, np.ndarray | None, np.ndarray,
+                         np.ndarray, int]:
+    """Lay a batch of chunks out flat for the device-side SHA-1 padding.
+
+    Returns ``(head, rest, offsets, lengths, n_blocks)``: the chunks'
+    bytes joined once, each from a new row, as (rows, 128) little-endian
+    uint32 words split at :func:`sha1_flat_rows`' first size (``rest`` is
+    ``None`` when every byte fits in ``head``: the rest is zeros the
+    device already holds); int32 byte offsets and lengths per lane
+    (``lanes >= len(chunks)``; the spare lanes hash the empty message);
+    and the block axis, bucketed as in :func:`sha1_pad_batch` with
+    ``max_len`` authoritative.
+    """
+    lens = np.fromiter(map(len, chunks), np.int64, count=len(chunks))
+    n_blocks = _block_cap(int(lens.max(initial=0)), max_len)
+    head_rows, rows = sha1_flat_rows(lanes, n_blocks)
+    pads = -lens % FLAT_ROW
+    used = int(lens.sum() + pads.sum())
+    fill = FLAT_ROW * (head_rows if used <= FLAT_ROW * head_rows else rows)
+    parts = [_ZERO_ROW] * (2 * len(chunks) + 1)
+    parts[0:-1:2] = chunks
+    parts[1:-1:2] = [_ZERO_ROW[:p] for p in pads.tolist()]
+    parts[-1] = bytes(fill - used)
+    flat = np.frombuffer(b"".join(parts), dtype="<u4").reshape(-1, 128)
+    head, rest = flat[:head_rows], flat[head_rows:]
+    offsets = np.zeros(lanes, np.int32)
+    np.cumsum((lens + pads)[:-1], out=offsets[1:len(chunks)])
+    lengths = np.zeros(lanes, np.int32)
+    lengths[:len(chunks)] = lens
+    return head, (rest if len(rest) else None), offsets, lengths, n_blocks
+
+
 def sha1_pad_batch(chunks: list[bytes], max_len: int | None = None,
                    exact: bool = False) -> tuple[np.ndarray, np.ndarray]:
-    """Pad a batch of chunks to a common block count.
+    """Pad a batch of chunks to a common block count, on the host.
 
+    Only the fused ingest (``kernels.ops.fused_hash_encode_blobs``) and
+    tests use it; the staged path pads on the device (:func:`sha1_pack`).
     Returns ``(blocks, n_blocks)`` where ``blocks`` is
     (B, max_blocks, 16) uint32 and ``n_blocks`` (B,) int32 gives the number
     of *real* blocks per chunk (trailing blocks are zero and must be
@@ -67,12 +142,9 @@ def sha1_pad_batch(chunks: list[bytes], max_len: int | None = None,
     counts = np.array([p.shape[0] for p in padded], dtype=np.int32)
     cap = max(int(counts.max()), 1)
     if max_len is not None:
-        fixed = (max_len + 9 + 63) // 64
-        if cap > fixed:
-            raise ValueError(
-                f"chunk needs {cap} SHA-1 blocks > fixed cap {fixed} "
-                f"(max_len={max_len}); raise the caller's max_len")
-        cap = fixed if exact else min(1 << (cap - 1).bit_length(), fixed)
+        cap = _block_cap(max(map(len, chunks)), max_len)
+        if exact:
+            cap = sha1_blocks(max_len)
     out = np.zeros((len(chunks), cap, 16), dtype=np.uint32)
     for i, p in enumerate(padded):
         out[i, : p.shape[0]] = p

@@ -309,40 +309,65 @@ def _sha1_words_loop(blocks: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.fori_loop(0, words.shape[0], block, h0).T
 
 
-def _sha1_ref_body(blocks: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
-    """Traced body of the standalone jitted SHA-1 oracle.
+def _sha1_flat_body(head, rest, offsets, lengths, n_blocks):
+    """Traced body of the jitted SHA-1 oracle: the device-side padding of
+    ``sha1.message_blocks``, then the oracle's loop.
 
-    Kept separate from ``_sha1_words_loop`` so the fused ingest oracle
+    Kept apart from ``_sha1_words_loop`` so the fused ingest oracle
     (which reuses the loop but counts ``TRACES.fused``) doesn't tick the
     sha1 family.
     """
     TRACES.sha1 += 1  # trace-time only: one increment per compiled shape
+    blocks, counts = sha1.message_blocks(jnp.concatenate([head, rest]),
+                                         offsets, lengths, n_blocks)
     return _sha1_words_loop(blocks, counts)
 
 
-_sha1_ref_loop = jax.jit(_sha1_ref_body)
+_sha1_flat_ref = jax.jit(_sha1_flat_body, static_argnames=("n_blocks",))
+
+
+@functools.lru_cache(maxsize=None)
+def _device_zeros(rows: int) -> jnp.ndarray:
+    """Zero (rows, 128) uint32 words kept on the device: the unused part
+    of a flat SHA-1 buffer, shipped once per launch shape."""
+    return jax.device_put(np.zeros((rows, 128), np.uint32))
 
 
 def sha1_digests(chunks: list[bytes], impl: str = "kernel") -> list[bytes]:
-    """Batched SHA-1 of byte chunks -> 20-byte digests (device hot path)."""
+    """Batched SHA-1 of byte chunks -> 20-byte digests, one launch.
+
+    The batch pads to a power of two of lanes, its block axis to the
+    longest chunk's need.
+    """
     if not chunks:
         return []
     with span("sears.engine.pack"):
-        blocks, counts = hashing.sha1_pad_batch(chunks)
-    words = to_host(sha1_digest_words(blocks, counts, impl=impl))
+        packed = hashing.sha1_pack(chunks, _pow2(len(chunks)),
+                                   max(map(len, chunks)))
+    words = to_host(sha1_digest_flat(*packed, impl=impl))
     with span("sears.engine.unpack"):
-        return hashing.digest_words_to_bytes(words)
+        return hashing.digest_words_to_bytes(words[:len(chunks)])
 
 
-def sha1_digest_words(blocks, counts, impl: str = "kernel") -> jnp.ndarray:
+def sha1_digest_flat(head: np.ndarray, rest: np.ndarray | None,
+                     offsets: np.ndarray, lengths: np.ndarray,
+                     n_blocks: int, impl: str = "kernel") -> jnp.ndarray:
+    """One SHA-1 launch over a ``hashing.sha1_pack`` batch -> (B, 5) words.
+
+    Ships the flat bytes once (``rest`` only when the batch spills past
+    ``head``; otherwise the device's own zeros stand in) with the int32
+    offsets and lengths; the message schedule is built on the device.
+    """
     LAUNCHES.sha1 += 1
     with span("sears.engine.dispatch"):
-        shipped(blocks, counts)
+        if rest is None:
+            rows = hashing.sha1_flat_rows(len(offsets), n_blocks)[1]
+            rest = _device_zeros(rows - len(head))
+        shipped(head, rest, offsets, lengths)
         if impl == "ref":
-            return _sha1_ref_loop(jnp.asarray(blocks, jnp.uint32),
-                                  jnp.asarray(counts, jnp.int32).reshape(-1))
-        return sha1.sha1_digest_words(blocks, counts,
-                                      interpret=not _on_tpu())
+            return _sha1_flat_ref(head, rest, offsets, lengths, n_blocks)
+        return sha1._sha1_flat(head, rest, offsets, lengths, n_blocks,
+                               interpret=not _on_tpu())
 
 
 # ----------------------------------------------------------- fused ingest --

@@ -44,8 +44,8 @@ HOT_SUFFIXES = ("_begin", "_issue")
 # host inside a begin/issue phase is a forced sync
 DEVICE_PRODUCERS = {
     "rs_apply", "gear_hash", "gear_fire", "gear_fire_issue",
-    "sha1_digest_words", "gf_matmul", "fused_hash_encode_blobs",
-    "flash_attention",
+    "sha1_digest_words", "sha1_digest_flat", "gf_matmul",
+    "fused_hash_encode_blobs", "flash_attention",
 }
 
 CONST_RE = re.compile(r"^[A-Z][A-Z0-9_]*$")

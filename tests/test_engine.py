@@ -103,13 +103,13 @@ def test_kernel_engine_hash_launch_shapes_stay_bucketed(monkeypatch):
     eng = KernelEngine(hash_batch=8, max_hash_len=1024)
     fixed_blocks = (1024 + 9 + 63) // 64  # 17; pow2(17) clamps back to 17
     seen_shapes = []
-    real = ops.sha1_digest_words
+    real = ops.sha1_digest_flat
 
-    def spy(blocks, counts, impl="kernel"):
-        seen_shapes.append(blocks.shape)
-        return real(blocks, counts, impl=impl)
+    def spy(head, rest, offsets, lengths, n_blocks, impl="kernel"):
+        seen_shapes.append((len(offsets), n_blocks, 16))
+        return real(head, rest, offsets, lengths, n_blocks, impl=impl)
 
-    monkeypatch.setattr(ops, "sha1_digest_words", spy)
+    monkeypatch.setattr(ops, "sha1_digest_flat", spy)
     chunks = [_data(100, seed=1), _data(1024, seed=3), _data(0, seed=4)]
     digests = eng.hash_chunks(chunks)
     assert digests == [hashlib.sha1(c).digest() for c in chunks]
@@ -118,6 +118,125 @@ def test_kernel_engine_hash_launch_shapes_stay_bucketed(monkeypatch):
     with pytest.raises(ValueError, match="max_len"):  # 5000 > max_hash_len
         eng.hash_chunks(chunks + [_data(5000, seed=2)])
     assert seen_shapes == [(4, fixed_blocks, 16)]  # nothing launched
+
+
+# ----------------------------------------- SHA-1 padding on the device ---
+# The staged engine ships each batch's chunk bytes once, in 512-byte rows
+# with offsets and lengths, and pads them on the device (sha1.message_blocks)
+
+MAX_HASH = 8192
+_EDGES = (0, 1, 55, 56, 63, 64, 119, 120, MAX_HASH - 9, MAX_HASH - 8,
+          MAX_HASH)
+# one row-sized max chunk: every lane fills its whole window of rows
+_FULL = 129 * 64 - 9
+
+
+def _edge_case(k, batch):
+    return MAX_HASH, [_EDGES[(k + j) % len(_EDGES)] for j in range(batch)]
+
+
+_FLAT_CASES = {f"len{n}-batch{b}": _edge_case(k, b)
+               for k, n in enumerate(_EDGES) for b in (1, 3, 128, 512)}
+# the last chunk ends on the head's last row; the lanes after it read
+# their windows from the zeros the device holds
+_FLAT_CASES["head-edge"] = (MAX_HASH, [4096] + [MAX_HASH] * 322 + [0] * 189)
+# every lane at its longest: the last lane's window ends on the buffer's
+# last row (one row short, a clamped gather would repeat a row)
+_FLAT_CASES["full"] = (_FULL, [_FULL] * 512)
+
+
+@pytest.mark.parametrize("case", sorted(_FLAT_CASES))
+def test_staged_sha1_pads_on_the_device_like_hashlib(case):
+    from repro.core import hashing
+
+    max_len, lengths = _FLAT_CASES[case]
+    rng = np.random.default_rng(len(case))
+    chunks = [rng.integers(0, 256, n, np.uint8).tobytes() for n in lengths]
+    if case in ("head-edge", "full"):  # the layout the case is named for
+        head, rest, offsets, _, n_blocks = hashing.sha1_pack(
+            chunks, 512, max_len)
+        rows = hashing.sha1_flat_rows(512, n_blocks)[1]
+        assert (rest is None and offsets[323] == head.nbytes
+                if case == "head-edge" else
+                offsets[-1] // 512 + -(-n_blocks // 8) == rows)
+    eng = KernelEngine(impl="ref", max_hash_len=max_len)
+    assert eng.hash_chunks(chunks) == [
+        hashlib.sha1(c).digest() for c in chunks]
+
+
+def test_staged_sha1_chunk_past_the_cap_raises():
+    # the cap is blocks(max_hash_len): 129 blocks hold up to _FULL bytes
+    eng = KernelEngine(impl="ref", max_hash_len=MAX_HASH)
+    assert eng.hash_chunks([_data(_FULL)]) == [
+        hashlib.sha1(_data(_FULL)).digest()]
+    with pytest.raises(ValueError, match="max_len"):
+        eng.hash_chunks([_data(10), _data(_FULL + 1, seed=1)])
+
+
+def _class_chunks(cmin, cavg, cmax, n, seed):
+    """The first ``n`` gear-CDC chunks of random bytes, one class's sizes."""
+    from repro.core.chunking import Chunker
+
+    data = np.random.default_rng(seed).integers(
+        0, 256, n * cmax, np.uint8).tobytes()
+    spans = Chunker(min_size=cmin, avg_size=cavg, max_size=cmax).chunk_spans(
+        data)
+    assert len(spans) > n
+    return [data[o:o + ln] for o, ln in spans[:n]]
+
+
+@pytest.mark.parametrize("cls", [(1024, 4096, 8192), (2048, 8192, 16384)],
+                         ids=["realtime", "archival"])
+def test_staged_sha1_ships_a_batch_of_chunks_about_once(cls):
+    from repro.kernels.launches import TRANSFERS
+
+    chunks = _class_chunks(*cls, 512, seed=cls[0])
+    eng = KernelEngine(impl="ref", max_hash_len=cls[2])
+    before = TRANSFERS.snapshot()
+    assert eng.hash_chunks(chunks) == [
+        hashlib.sha1(c).digest() for c in chunks]
+    # host-padded, the batch shipped 512 x 16 B per block of the longest
+    # chunk: about twice its bytes
+    moved = TRANSFERS.delta(before).h2d_bytes
+    assert moved <= 1.25 * sum(map(len, chunks)) + 8 * 512
+
+
+def test_staged_sha1_traces_stay_bounded_over_random_batches(monkeypatch):
+    from repro.kernels.launches import TRACES
+
+    eng = KernelEngine(impl="ref", max_hash_len=1024)
+    shapes = set()
+    real = ops.sha1_digest_flat
+
+    def spy(head, rest, offsets, lengths, n_blocks, impl="kernel"):
+        shapes.add((len(offsets), n_blocks, len(head),
+                    None if rest is None else len(rest)))
+        return real(head, rest, offsets, lengths, n_blocks, impl=impl)
+
+    monkeypatch.setattr(ops, "sha1_digest_flat", spy)
+    rng = np.random.default_rng(11)
+    before = TRACES.sha1
+    for _ in range(50):  # one class: 128 B min, 512 B mean, 1 KiB max
+        lengths = np.minimum(128 + rng.geometric(1 / 384, 512), 1024)
+        chunks = [rng.integers(0, 256, n, np.uint8).tobytes()
+                  for n in lengths]
+        assert eng.hash_chunks(chunks) == [
+            hashlib.sha1(c).digest() for c in chunks]
+    # 512 lanes, a block axis of 16 or 17: byte counts never add a shape
+    assert {s[:2] for s in shapes} <= {(512, 16), (512, 17)}
+    assert TRACES.sha1 - before <= 2
+
+
+def test_staged_sha1_custom_hash_fn_stays_on_the_host():
+    from repro.core import hashing
+    from repro.kernels.launches import LAUNCHES
+
+    eng = KernelEngine(hash_fn=hashing.fast_chunk_id, impl="ref")
+    chunks = [_data(n, seed=n) for n in (0, 64, 5000)]
+    before = LAUNCHES.sha1
+    assert eng.hash_chunks(chunks) == [
+        hashing.fast_chunk_id(c) for c in chunks]
+    assert LAUNCHES.sha1 == before
 
 
 def test_sha1_pad_batch_max_len_is_authoritative():

@@ -13,6 +13,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
 
@@ -99,15 +100,20 @@ def test_every_step_field_is_a_plain_number_named_by_a_span():
         assert isinstance(getattr(stats, name), (int, float)), name
 
 
-def _record(monkeypatch, name, sink, out_bytes):
+def _record(monkeypatch, name, sink, out_bytes, arg=np.shape):
     """Wrap a jitted oracle of ``ops``; sink gets (args, out nbytes)."""
     fn = getattr(ops, name)
 
     def call(*args):
         out = fn(*args)
-        sink.append((name, [np.shape(a) for a in args], out_bytes(out)))
+        sink.append((name, [arg(a) for a in args], out_bytes(out)))
         return out
     monkeypatch.setattr(ops, name, call)
+
+
+def _host_shape(a):
+    """A shipped argument's shape; ``None`` for one already on the device."""
+    return None if isinstance(a, jax.Array) else np.shape(a)
 
 
 def test_h2d_bytes_equal_the_launched_shapes(monkeypatch):
@@ -115,8 +121,8 @@ def test_h2d_bytes_equal_the_launched_shapes(monkeypatch):
     files = _files(10, seed=1)
     seen = []
     _record(monkeypatch, "_gear_fire_ref", seen, lambda out: None)
-    _record(monkeypatch, "_sha1_ref_loop", seen,
-            lambda out: int(np.prod(out.shape)) * 4)
+    _record(monkeypatch, "_sha1_flat_ref", seen,
+            lambda out: int(np.prod(out.shape)) * 4, arg=_host_shape)
     _record(monkeypatch, "_gf_ref_jit", seen,
             lambda out: int(np.prod(out.shape)))
     before = TRANSFERS.snapshot()
@@ -125,15 +131,18 @@ def test_h2d_bytes_equal_the_launched_shapes(monkeypatch):
 
     stream = sum(len(d) for _, d in files)
     gear = [shapes for name, shapes, _ in seen if name == "_gear_fire_ref"]
-    sha1 = [shapes for name, shapes, _ in seen if name == "_sha1_ref_loop"]
+    sha1 = [shapes for name, shapes, _ in seen if name == "_sha1_flat_ref"]
     gf = [(shapes, b) for name, shapes, b in seen
           if name == "_gf_ref_jit"]
     assert gear == [[(gear_cdc.bucket_len(stream),), ()]]
     assert len(sha1) > 1 and gf  # several hash batches, some GF buckets
     h2d = gear_cdc.bucket_len(stream) + 4  # the stream and its mask
-    for blocks, counts in sha1:
-        assert blocks[1:] == (blocks[1], 16) and counts == blocks[:1]
-        h2d += 4 * int(np.prod(blocks)) + 4 * counts[0]
+    for head, rest, offsets, lengths, _ in sha1:
+        # the chunk bytes in 512-byte rows, then their offsets and lengths;
+        # the rest of the rows ship only when the bytes spill past the head
+        assert head[1] == 128 and offsets == lengths
+        h2d += 4 * int(np.prod(head)) + 8 * offsets[0]
+        h2d += 4 * int(np.prod(rest)) if rest is not None else 0
     for (matrix, data), back in gf:
         assert matrix == (CLASS.n - CLASS.k, CLASS.k)  # the parity block
         assert data[1] == CLASS.k and data[2] % 512 == 0

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import gf256
+from repro.core import gf256, hashing
 from repro.core.rs_code import RSCode, decode_matrix, parity_matrix
 from repro.kernels import gear_cdc, gf_matmul, ops, sha1
 from repro.kernels.launches import TRACES
@@ -66,6 +66,16 @@ def _sha1_blocks(max_len: int) -> int:
     return (max_len + 9 + 63) // 64
 
 
+def _flat_case(max_len: int):
+    m = _sha1_blocks(max_len)
+    head, rows = hashing.sha1_flat_rows(512, m)
+    u32, i32 = jnp.uint32, jnp.int32
+    return (sha1._sha1_flat,
+            [((head, 128), u32), ((rows - head, 128), u32), ((512,), i32),
+             ((512,), i32)],
+            {"n_blocks": m})
+
+
 # (kernel, argument shapes/dtypes, static kwargs) at the widths the served
 # path launches: a 64 MiB put-window gear stream, both preset codes, the
 # SHA-1 caps of the staged (8 KiB / 16 KiB chunks) and fused paths
@@ -99,6 +109,9 @@ def _case(name):
             sha1._sha1_padded,
             [((512, _sha1_blocks(16384), 16), u32), ((512, 1), i32)],
             {"tile": sha1.TILE_B}),
+        # the staged engine's launch: flat rows padded on the device
+        "sha1_flat_8KiB_cap": _flat_case(8192),
+        "sha1_flat_archival_cap": _flat_case(16384),
         # a 64 MiB window's bucket holds thousands of chunks, cut into
         # launches of FUSED_LANES: such batches ran out of VMEM with
         # messages on sublanes
@@ -121,6 +134,7 @@ def _case(name):
 @pytest.mark.parametrize("name", [
     "gear_fire_64MiB", "gf_encode_10_5", "gf_encode_14_10",
     "gf_decode_5_5", "sha1_8KiB_cap", "sha1_archival_cap",
+    "sha1_flat_8KiB_cap", "sha1_flat_archival_cap",
     "fused_realtime", "fused_archival"])
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes, static = _case(name)
@@ -148,6 +162,7 @@ def test_gear_fire_returns_one_bit_per_position_unpadded(one_chip):
 TRACE_NAMES = {"gear_fire_64MiB": ["_gear_fire_padded"],
                "gf_encode_10_5": ["_gf_matmul_padded"],
                "sha1_8KiB_cap": ["_sha1_padded"],
+               "sha1_flat_archival_cap": ["_sha1_padded"],
                "fused_realtime": ["_sha1_padded", "_gf_matmul_padded"]}
 
 
