@@ -306,6 +306,10 @@ class SchedulerStats:
     engine_dispatch_s: float = 0.0
     engine_wait_s: float = 0.0
     engine_unpack_s: float = 0.0
+    # the part of pack + unpack a fused engine spends on the GF rows of
+    # its speculative encode (filling them, cutting parity into pieces);
+    # stays 0 on a staged engine
+    engine_spec_s: float = 0.0
     # bytes the foreground windows moved (kernels.launches.TRANSFERS)
     h2d_bytes: int = 0
     d2h_bytes: int = 0
@@ -333,6 +337,7 @@ SPAN_FIELDS = {
     "sears.engine.dispatch": "engine_dispatch_s",
     "sears.engine.wait": "engine_wait_s",
     "sears.engine.unpack": "engine_unpack_s",
+    "sears.engine.spec_encode": "engine_spec_s",
 }
 
 

@@ -439,13 +439,14 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
                      for lo in range(0, len(idxs), Bp)]
     for Lp, Bp, idxs in launches:
         with span("sears.engine.pack"):
-            data = np.zeros((Bp, code.k, Lp), dtype=np.uint8)
-            group: list[bytes] = []
-            for row, i in enumerate(idxs):
-                data[row] = rs_code.pack_blob(blobs[i], code.k,
-                                              piece_lens[i], Lp)
-                group.append(blobs[i])
-            group += [b""] * (Bp - len(idxs))
+            # the GF rows: work a staged engine does only for chunks
+            # dedup found new, timed apart as the speculation's host cost
+            with span("sears.engine.spec_encode"):
+                data = np.zeros((Bp, code.k, Lp), dtype=np.uint8)
+                for row, i in enumerate(idxs):
+                    data[row] = rs_code.pack_blob(blobs[i], code.k,
+                                                  piece_lens[i], Lp)
+            group = [blobs[i] for i in idxs] + [b""] * (Bp - len(idxs))
             blocks, counts = hashing.sha1_pad_batch(
                 group, max_len=code.k * Lp, exact=True)
         LAUNCHES.fused += 1
@@ -467,6 +468,7 @@ def fused_hash_encode_blobs(code, blobs: list[bytes], impl: str = "kernel"
             digests = hashing.digest_words_to_bytes(words[:len(idxs)])
             for row, i in enumerate(idxs):
                 ids[i] = digests[row]
-            rs_code.unpack_encoded(code, blobs, piece_lens, idxs, parity,
-                                   pieces)
+            with span("sears.engine.spec_encode"):
+                rs_code.unpack_encoded(code, blobs, piece_lens, idxs,
+                                       parity, pieces)
     return ids, pieces  # type: ignore[return-value]

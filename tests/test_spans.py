@@ -31,6 +31,7 @@ GET_FIELDS = ("get_plan_s", "get_read_s", "get_hint_s", "get_decode_s",
               "get_assemble_s")
 ENGINE_FIELDS = ("engine_pack_s", "engine_dispatch_s", "engine_wait_s",
                  "engine_unpack_s")
+SPEC_FIELD = "engine_spec_s"  # inside pack + unpack, fused engine only
 BYTE_FIELDS = ("h2d_bytes", "d2h_bytes")
 CLASS = StorageClass(name="realtime", n=10, k=5, chunk_min=1024,
                      chunk_avg=4096, chunk_max=8192, binding="ulb")
@@ -94,10 +95,34 @@ def test_every_step_field_is_a_plain_number_named_by_a_span():
     stats = _store().scheduler().stats
     fields = {f.name for f in dataclasses.fields(stats)}
     assert set(SPAN_FIELDS.values()) <= fields
-    assert set(PUT_FIELDS + GET_FIELDS + ENGINE_FIELDS) == set(
-        SPAN_FIELDS.values())
+    assert set(PUT_FIELDS + GET_FIELDS + ENGINE_FIELDS
+               + (SPEC_FIELD,)) == set(SPAN_FIELDS.values())
     for name in fields:
         assert isinstance(getattr(stats, name), (int, float)), name
+
+
+@pytest.mark.parametrize("engine", ["fused", "kernel", "numpy"])
+def test_spec_encode_span_opens_twice_per_fused_launch(engine):
+    """The GF-side host work of the speculative encode is timed once when
+    a fused launch's rows are filled and once when its parity is cut,
+    never per chunk and never on a staged engine."""
+    store = SEARSStore(classes=[CLASS], num_clusters=3,
+                       node_capacity=1 << 26, engine=engine, sanitize=False)
+    sched = store.scheduler()
+    s0 = _stats(sched)
+    before = snapshot_all()
+    _put(sched, _files(6, seed=5))
+    d = delta_all(before)
+    s1 = _stats(sched)
+    fused = d["launches"].fused
+    assert d["spans"].count.get("sears.engine.spec_encode", 0) == 2 * fused
+    spec = _delta(s1, s0, SPEC_FIELD)
+    if engine == "fused":
+        assert fused > 1 and spec > 0  # several piece-length buckets
+        assert spec <= (_delta(s1, s0, "engine_pack_s")
+                        + _delta(s1, s0, "engine_unpack_s"))
+    else:
+        assert fused == 0 and spec == 0
 
 
 def _record(monkeypatch, name, sink, out_bytes, arg=np.shape):
